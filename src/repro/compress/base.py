@@ -149,9 +149,11 @@ def column_bits(seed: jax.Array, cols: jax.Array) -> jax.Array:
 
 
 def uniform_columns(seed: jax.Array, cols: jax.Array) -> jax.Array:
-    """U[0, 1) from the top 24 bits of :func:`column_bits` (fp32-exact)."""
-    return (column_bits(seed, cols) >> 8).astype(jnp.float32) * np.float32(
-        2.0 ** -24)
+    """U[0, 1) from the top 24 bits of :func:`column_bits` (fp32-exact).
+    The shifted value is below 2^24, so the int32 hop changes nothing; it
+    exists because Mosaic has no uint32 -> float32 cast."""
+    top = (column_bits(seed, cols) >> 8).astype(jnp.int32)
+    return top.astype(jnp.float32) * np.float32(2.0 ** -24)
 
 
 # ---------------------------------------------------------------------------

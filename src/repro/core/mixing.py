@@ -166,26 +166,21 @@ def _hub():
 
 def _meter(tel, params: PyTree, spec: CommSpec, *, phase: str, step: int,
            role: str, wires=None) -> None:
-    """Emit one ``comm_round`` record; metering must never break a round,
-    so accounting errors degrade to a warning."""
-    try:
-        from repro.obs import meters as obs_meters
-        sharded = spec.uses_sharded()
-        km = 1
-        if sharded and spec.mesh is not None:
-            names = node_axis_names(spec.mesh, spec.node_axis)
-            km = _model_names_count(spec.mesh, spec.model_axis, names)[1]
-        fields = obs_meters.comm_round_fields(
-            params, phase=phase, topology=spec.topology,
-            n_nodes=spec.n_nodes, step=int(step), n_pods=spec.n_pods,
-            backend=spec.backend, sharded=sharded,
-            comm_dtype=spec.comm_dtype, compressor=spec.compressor,
-            global_compressor=spec.global_compressor, model_shards=km,
-            wires=wires, role=role)
-        tel.emit("comm_round", **fields)
-    except Exception as e:                           # pragma: no cover
-        warnings.warn(f"mixing: comm_round meter failed ({e}); "
-                      f"round unaffected")
+    """Emit one ``comm_round`` record."""
+    from repro.obs import meters as obs_meters
+    sharded = spec.uses_sharded()
+    km = 1
+    if sharded and spec.mesh is not None:
+        names = node_axis_names(spec.mesh, spec.node_axis)
+        km = _model_names_count(spec.mesh, spec.model_axis, names)[1]
+    fields = obs_meters.comm_round_fields(
+        params, phase=phase, topology=spec.topology,
+        n_nodes=spec.n_nodes, step=int(step), n_pods=spec.n_pods,
+        backend=spec.backend, sharded=sharded,
+        comm_dtype=spec.comm_dtype, compressor=spec.compressor,
+        global_compressor=spec.global_compressor, model_shards=km,
+        wires=wires, role=role)
+    tel.emit("comm_round", **fields)
 
 
 def meter_round(params: PyTree, spec: CommSpec, *, phase: str,
@@ -254,6 +249,18 @@ def node_axis_names(mesh: jax.sharding.Mesh, node_axis: str = "data"
         return (node_axis,)
     raise ValueError(f"node_axis must be 'data', 'pod', or a mesh axis "
                      f"name, got {node_axis!r}")
+
+
+def auto_axes(mesh: jax.sharding.Mesh) -> jax.sharding.Mesh:
+    """``mesh`` with every axis of type Auto.  The sharded rounds and the
+    train step leave layouts to the compiler; under Explicit axes (the
+    default of ``jax.make_mesh``) their outputs would carry sharding types
+    that refuse unsharded operands and rolls over the node axis."""
+    auto = jax.sharding.AxisType.Auto
+    if all(t == auto for t in mesh.axis_types):
+        return mesh
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                             axis_types=(auto,) * mesh.devices.ndim)
 
 
 def node_shard_count(mesh: Optional[jax.sharding.Mesh],
@@ -527,7 +534,6 @@ def make_shard_map_mixer(mesh: jax.sharding.Mesh, axis_name: str,
                          topology: str, step: int = 0) -> Callable:
     """Build ``f(x_stacked) -> W @ x_stacked`` running as shard_map over
     ``axis_name`` — the explicit runtime equivalent of :func:`mix_pytree`."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis_name]
@@ -537,7 +543,8 @@ def make_shard_map_mixer(mesh: jax.sharding.Mesh, axis_name: str,
         return gossip_ppermute(x, axis_name, n, weights)
 
     spec = P(axis_name)
-    return shard_map(node_fn, mesh=mesh, in_specs=(spec,), out_specs=spec)
+    return jax.shard_map(node_fn, mesh=auto_axes(mesh),
+                         in_specs=(spec,), out_specs=spec)
 
 
 # ---------------------------------------------------------------------------
@@ -913,7 +920,6 @@ def communicate_sharded(params: PyTree, spec: Optional[CommSpec] = None, *,
     collectives"), superseding ``compressor``/``comm_dtype`` for those
     phases; same ``(mixed, new_ef_state)`` contract.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels import mixing_pallas
 
@@ -1072,8 +1078,9 @@ def communicate_sharded(params: PyTree, spec: Optional[CommSpec] = None, *,
             + (jnp.asarray(Mstack), jnp.asarray(dstack))
 
     out_specs = (xspec, bar_spec, P()) if with_residual else xspec
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh),
+                       in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     out = fn(*operands)
 
     if with_residual:
@@ -1119,7 +1126,6 @@ def _communicate_sharded_compressed(params: PyTree, *, compressor, ef_state,
     uncompressed path (every backend applies the same cast to ``q``,
     keeping parity and the constant fixed point).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels import mixing_pallas
     from repro.models.sharding import wire_column_spec
@@ -1150,8 +1156,9 @@ def _communicate_sharded_compressed(params: PyTree, *, compressor, ef_state,
             qbar = jax.lax.psum(jnp.sum(q, axis=0, keepdims=True), names) / n
             return xb + (qbar - q)
 
-        fn = shard_map(body, mesh=mesh, in_specs=(xspec,) + wire_specs,
-                       out_specs=xspec, check_rep=False)
+        fn = jax.shard_map(body, mesh=auto_axes(mesh),
+                           in_specs=(xspec,) + wire_specs,
+                           out_specs=xspec, check_vma=False)
         return unflatten(fn(xf, *wire_arrs)), new_ef
 
     out = _sharded_compensated_gossip(
@@ -1238,7 +1245,6 @@ def _sharded_compensated_gossip(params: PyTree, wires, *, compressor,
     payloads (the overlap double buffer) — the compensation preserves the
     node average for any transmitted estimate, which is exactly why the
     overlapped mode reuses this round unchanged."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels import mixing_pallas
     from repro.models.sharding import wire_column_spec
@@ -1272,8 +1278,9 @@ def _sharded_compensated_gossip(params: PyTree, wires, *, compressor,
             interpret=interpret)
 
     in_specs = (xspec, P(names), P(names)) + wire_specs
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=xspec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh),
+                       in_specs=in_specs, out_specs=xspec,
+                       check_vma=False)
     out = fn(xf, jnp.asarray(Mstack), jnp.asarray(wstack), *wire_arrs)
     return unflatten(out)
 
@@ -1446,7 +1453,6 @@ def _overlap_finish_sharded_dense(params: PyTree, q: PyTree,
     (``start_round``), so the f32 re-pack is an exact upcast and the
     ppermute payload is re-cast to the wire dtype — the bytes crossing
     the ICI match the synchronous path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels import mixing_pallas
 
@@ -1481,9 +1487,9 @@ def _overlap_finish_sharded_dense(params: PyTree, q: PyTree,
         return mixing_pallas.shard_comp_mix_block(
             xb, qb, qs, wr[0], Mr[0], block_d=block_d, interpret=interpret)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(xspec, xspec, P(names), P(names)),
-                   out_specs=xspec, check_rep=False)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh),
+                       in_specs=(xspec, xspec, P(names), P(names)),
+                       out_specs=xspec, check_vma=False)
     return unflatten(fn(xf, qf, jnp.asarray(Mstack), jnp.asarray(wstack)))
 
 
@@ -1610,7 +1616,6 @@ def _push_sum_sharded(joint: PyTree, *, W: jax.Array, n_nodes: int,
     ``r+q``), so asymmetric W needs no new wiring — only the runtime
     Mstack/dstack (transpose-free: the weight column is mixed by the same
     per-shard kernel as the parameters, no Wᵀ ever forms)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels import mixing_pallas
 
@@ -1642,9 +1647,9 @@ def _push_sum_sharded(joint: PyTree, *, W: jax.Array, n_nodes: int,
         return mixing_pallas.shard_mix_block(
             xb, xs, dr[0], Mr[0], block_d=block_d, interpret=interpret)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(xspec, P(names), P(names)),
-                   out_specs=xspec, check_rep=False)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh),
+                       in_specs=(xspec, P(names), P(names)),
+                       out_specs=xspec, check_vma=False)
     return unflatten(fn(xf, Mstack, dstack))
 
 
@@ -1699,25 +1704,21 @@ def communicate_push_sum(params: PyTree, weight: jax.Array, *,
         # data, not programs — DESIGN.md §2.5), so the static shift/send
         # accounting does not apply: report one send's worth of payload
         # bytes from the live tree and flag sends as data-dependent (-1)
-        try:
-            from repro.obs import meters as obs_meters
-            sizes = obs_meters.per_node_leaf_sizes(params, n)
-            elem = (np.dtype(comm_dtype).itemsize
-                    if comm_dtype is not None else 4)
-            leaves = jax.tree.leaves(params)
-            tel.emit(
-                "comm_round", phase="push_sum", role="round",
-                topology="runtime", backend=backend, sharded=sharded,
-                n_nodes=int(n), sends=-1,
-                compression=(compressor.name if compressor is not None
-                             else "none"),
-                measured_bytes=int(sum(sizes)) * int(elem),
-                analytic_bytes=None,
-                traced=bool(leaves)
-                and isinstance(leaves[0], jax.core.Tracer))
-        except Exception as e:                       # pragma: no cover
-            warnings.warn(f"mixing: push-sum comm meter failed ({e}); "
-                          f"round unaffected")
+        from repro.obs import meters as obs_meters
+        sizes = obs_meters.per_node_leaf_sizes(params, n)
+        elem = (np.dtype(comm_dtype).itemsize
+                if comm_dtype is not None else 4)
+        leaves = jax.tree.leaves(params)
+        tel.emit(
+            "comm_round", phase="push_sum", role="round",
+            topology="runtime", backend=backend, sharded=sharded,
+            n_nodes=int(n), sends=-1,
+            compression=(compressor.name if compressor is not None
+                         else "none"),
+            measured_bytes=int(sum(sizes)) * int(elem),
+            analytic_bytes=None,
+            traced=bool(leaves)
+            and isinstance(leaves[0], jax.core.Tracer))
 
     if compressor is not None and compressor.lossy:
         if sharded:
@@ -1795,7 +1796,6 @@ def _communicate_sharded_collective(params: PyTree, *, compressor, ef_state,
     callers get their own message instead of an opaque shard_map trace
     failure.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.compress import collective as ccol
     from repro.kernels import mixing_pallas
@@ -1865,9 +1865,9 @@ def _communicate_sharded_collective(params: PyTree, *, compressor, ef_state,
                                 tiled=True)
         return ccol.dequant_blocks(gc, ccol.exponent_scales(ge), qb)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(wspec, wspec),
-                   out_specs=P(None, mnames) if mnames else P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=auto_axes(mesh), in_specs=(wspec, wspec),
+                       out_specs=P(None, mnames) if mnames else P(),
+                       check_vma=False)
     r = fn(codes1, ccol.scale_exponents(scales1))               # (p, Dp)
     per = n // pods
     r_rows = jnp.broadcast_to(r[:, None], (pods, per, Dp)).reshape(n, Dp)

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import topology as topo
 
@@ -77,6 +78,11 @@ KERNEL_PHASES = ("gossip", "global", "pod_avg")
 # dispatch instead of riding the concatenation staging buffer
 # (DistConfig.pallas_leaf_threshold overrides per run).
 LEAF_DISPATCH_THRESHOLD = 262_144
+
+
+# Every kernel matmul is an fp32 mix like the reference backend's: Mosaic's
+# default contraction precision for fp32 operands is not guaranteed to be fp32.
+_FP32 = jax.lax.Precision.HIGHEST
 
 
 def _default_interpret() -> bool:
@@ -236,7 +242,8 @@ def _mix_kernel(*refs, with_g: bool, with_residual: bool, wire: bool):
     # wire-dtype cast applies to the M term only: neighbor traffic for gossip
     # (d carries the uncast self term), everything for averages (d = 0)
     onwire = x.astype(jnp.bfloat16).astype(jnp.float32) if wire else x
-    mixed = jnp.dot(m_ref[...], onwire, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(m_ref[...], onwire, preferred_element_type=jnp.float32,
+                    precision=_FP32)
     mixed = mixed + d_ref[...] * x
     o_ref[...] = mixed.astype(o_ref.dtype)
 
@@ -291,8 +298,10 @@ def _mix_flat(xf: jax.Array, gf: Optional[jax.Array],
     if with_residual:
         out_shape.append(jax.ShapeDtypeStruct((1, Dp), jnp.float32))
         out_specs.append(pl.BlockSpec((1, bd), tile))
+        # the residual is a scalar accumulated across the grid: Mosaic
+        # stores scalars only to SMEM
         out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
     kernel = functools.partial(_mix_kernel, with_g=with_g,
                                with_residual=with_residual, wire=wire)
@@ -559,7 +568,8 @@ def _cmix_kernel(*refs, kind: str, with_ef: bool, wire: bool):
         q = q_ref[...].astype(jnp.float32)
     if wire:
         q = q.astype(jnp.bfloat16).astype(jnp.float32)
-    corr = jnp.dot(m_ref[...], q, preferred_element_type=jnp.float32) \
+    corr = jnp.dot(m_ref[...], q, preferred_element_type=jnp.float32,
+                   precision=_FP32) \
         - w_ref[...] * q
     o_ref[...] = (x + corr).astype(o_ref.dtype)
 
@@ -929,7 +939,8 @@ def _shard_cmix_kernel(x_ref, q_ref, qs_ref, w_ref, m_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)                       # (m, bd)
     q = q_ref[...].astype(jnp.float32)                       # (m, bd)
     qs = qs_ref[...].astype(jnp.float32)                     # (K·m, bd)
-    corr = jnp.dot(m_ref[...], qs, preferred_element_type=jnp.float32) \
+    corr = jnp.dot(m_ref[...], qs, preferred_element_type=jnp.float32,
+                   precision=_FP32) \
         - w_ref[...] * q
     o_ref[...] = (x + corr).astype(o_ref.dtype)
 
@@ -984,7 +995,8 @@ def _shard_mix_kernel(x_ref, xs_ref, d_ref, m_ref, *out_refs,
     o_ref = out_refs[0]
     x = x_ref[...].astype(jnp.float32)                       # (m, bd)
     xs = xs_ref[...].astype(jnp.float32)                     # (K·m, bd)
-    mixed = jnp.dot(m_ref[...], xs, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(m_ref[...], xs, preferred_element_type=jnp.float32,
+                    precision=_FP32)
     mixed = mixed + d_ref[...] * x
     o_ref[...] = mixed.astype(o_ref.dtype)
 

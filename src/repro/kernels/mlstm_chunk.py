@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_BIG = -1e9
 
 
@@ -127,7 +123,7 @@ def mlstm_chunk(q: jax.Array, k: jax.Array, v: jax.Array, log_i: jax.Array,
             pltpu.VMEM((dk,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qh, kh, vh, lih, lfh)

@@ -11,12 +11,9 @@ from typing import Optional
 import jax
 
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.mixing_pallas import _default_interpret
 from repro.kernels.mlstm_chunk import mlstm_chunk as _mlstm
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(

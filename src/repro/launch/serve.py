@@ -8,6 +8,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_model_config, list_archs
+from repro.launch.mesh import use_compile_cache
 from repro.models import make_model
 from repro.serve import BatchedServer, Engine, Request
 
@@ -28,6 +29,7 @@ def main() -> None:
                          "serve/decode spans to this path")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_model_config(args.arch, reduced=not args.full_config)
     if not cfg.causal:
         raise SystemExit(f"{args.arch} is encoder-only: no decode serving")

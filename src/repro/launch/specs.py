@@ -28,25 +28,10 @@ from repro.train.state import (TrainState, stack_for_nodes, stacked_axes,
                                state_axes)
 
 PyTree = Any
-def _IS_AXES(x):
-    return isinstance(x, tuple)
 
 
 def _sds(shape, dtype) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(tuple(int(s) for s in shape), dtype)
-
-
-def _shardings(axes_tree: PyTree, mode: str, mesh: Mesh,
-               sds_tree: Optional[PyTree] = None) -> PyTree:
-    """Shape-aware sharding resolution (skips non-divisible dims)."""
-    if sds_tree is None:
-        return jax.tree.map(
-            lambda a: NamedSharding(mesh, shd.logical_to_spec(a, mode, mesh)),
-            axes_tree, is_leaf=_IS_AXES)
-    return jax.tree.map(
-        lambda a, s: NamedSharding(
-            mesh, shd.logical_to_spec(a, mode, mesh, shape=s.shape)),
-        axes_tree, sds_tree, is_leaf=_IS_AXES)
 
 
 # ---------------------------------------------------------------------------
@@ -126,11 +111,11 @@ def train_specs(cfg: ModelConfig, mesh: Mesh, shape: InputShape, *,
     state_axes_tree = state_axes(
         st_axes, optimizer.name,
         extras=algo_lib.extras_axes(dist, st_axes, axes))
-    state_sh = _shardings(state_axes_tree, mode, mesh, state_sds)
+    state_sh = shd.shardings_for(state_axes_tree, mode, mesh, state_sds)
 
     b_sds, b_axes = batch_specs(cfg, n_nodes, shape.global_batch,
                                 shape.seq_len)
-    b_sh = _shardings(b_axes, mode, mesh, b_sds)
+    b_sh = shd.shardings_for(b_axes, mode, mesh, b_sds)
     repl = NamedSharding(mesh, P())
     return TrainSpecs(state_sds=state_sds, state_shardings=state_sh,
                       batch_sds=b_sds, batch_shardings=b_sh,
@@ -176,14 +161,14 @@ def serve_specs(cfg: ModelConfig, mesh: Mesh, shape: InputShape, *,
     mode = ("serve_cp" if context_parallel
             else {"tp": "serve_tp", "2d": "serve_2d",
                   "tp_seq": "serve_tp_seq"}[param_sharding])
-    params_sh = _shardings(axes, mode, mesh, params_sds)
+    params_sh = shd.shardings_for(axes, mode, mesh, params_sds)
 
     if shape.kind == "prefill":
         b_sds, b_axes = batch_specs(cfg, None, shape.global_batch,
                                     shape.seq_len)
         b_sds.pop("targets", None)
         b_axes.pop("targets", None)
-        b_sh = _shardings(b_axes, mode, mesh, b_sds)
+        b_sh = shd.shardings_for(b_axes, mode, mesh, b_sds)
         return ServeSpecs(params_sds, params_sh, b_sds, b_sh,
                           None, None, None, None, None, None, mode)
 
@@ -192,7 +177,7 @@ def serve_specs(cfg: ModelConfig, mesh: Mesh, shape: InputShape, *,
     cache_sds = jax.eval_shape(
         lambda: model.init_cache(B, shape.seq_len))
     cache_axes = model.cache_axes()
-    cache_sh = _shardings(cache_axes, mode, mesh, cache_sds)
+    cache_sh = shd.shardings_for(cache_axes, mode, mesh, cache_sds)
     tok_axes = ("batch", None)
     pos_axes = ("batch",)
     return ServeSpecs(

@@ -1,8 +1,11 @@
-"""Production training launcher: ``python -m repro.launch.train --arch <id>``.
+"""Training launcher: ``python -m repro.launch.train --arch <id>``.
 
-On this CPU container it runs the reduced config on simulated nodes; on a real
-TPU slice the same entry point builds the production mesh and shards the
-decentralized state per DESIGN.md §4.
+Runs the reduced config by default and the published widths with
+``--full-config``.  On one device the ``--nodes`` nodes stack on it.  With
+several devices (e.g. a four-chip TPU host) it builds a ``("data",)`` mesh
+over all of them and each device holds ``nodes / device_count`` nodes
+(``launch.mesh.node_mesh``).  Compiles go to the persistent cache that
+``launch.mesh.use_compile_cache`` sets up.
 """
 from __future__ import annotations
 
@@ -13,6 +16,7 @@ import jax
 from repro.configs import (ALGORITHMS, DataConfig, DistConfig,
                            OptimizerConfig, TrainConfig, get_model_config,
                            list_archs)
+from repro.launch.mesh import node_mesh, use_compile_cache
 from repro.train import Trainer
 
 
@@ -103,6 +107,7 @@ def main() -> None:
                          "(serializes the pipeline it measures)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_model_config(args.arch, reduced=not args.full_config)
     tcfg = TrainConfig(
         model=cfg,
@@ -141,8 +146,9 @@ def main() -> None:
             sinks.insert(0, obs.JsonlSink(
                 os.path.join(args.telemetry_dir, "telemetry.jsonl")))
         telemetry = obs.Telemetry(sinks=sinks, fence=args.trace_fence)
-    tr = Trainer(tcfg, n_nodes=args.nodes, with_consensus=True,
-                 fault_schedule=fault_schedule, telemetry=telemetry)
+    tr = Trainer(tcfg, n_nodes=args.nodes, mesh=node_mesh(args.nodes),
+                 with_consensus=True, fault_schedule=fault_schedule,
+                 telemetry=telemetry)
     state = tr.init_state(jax.random.PRNGKey(0))
     tr.run(state, steps=args.steps)
     if telemetry is not None:

@@ -71,7 +71,8 @@ def _rules(mode: str, mesh: Mesh) -> dict:
 
 def logical_to_spec(axes: Tuple[Optional[str], ...], mode: str, mesh: Mesh,
                     shape: Optional[Tuple[int, ...]] = None) -> P:
-    """Resolve logical axes to a PartitionSpec.  With ``shape`` given, a mesh
+    """Resolve logical axes to a PartitionSpec.  Mesh axes absent from
+    ``mesh`` resolve to replicated.  With ``shape`` given, a mesh
     axis is applied only when the dim size is divisible by it — pjit argument
     shardings require exact divisibility (e.g. kv_heads=8 on a model=16 axis
     stays replicated)."""
@@ -83,9 +84,11 @@ def logical_to_spec(axes: Tuple[Optional[str], ...], mode: str, mesh: Mesh,
             phys.append(None)
             continue
         p = rules.get(a, None)
-        # never map two tensor dims to the same mesh axis
+        # never map two tensor dims to the same mesh axis, nor a dim to an
+        # axis this mesh lacks (a ("data",) mesh has no "model" axis)
         flat = tuple(p) if isinstance(p, tuple) else (p,)
-        if p is None or any(f in used for f in flat if f is not None):
+        if p is None or any(f in used or f not in mesh_sizes
+                            for f in flat if f is not None):
             phys.append(None)
             continue
         if shape is not None:
@@ -105,10 +108,18 @@ def specs_for(axes_tree: PyTree, mode: str, mesh: Mesh) -> PyTree:
                         axes_tree, is_leaf=_IS_AXES)
 
 
-def shardings_for(axes_tree: PyTree, mode: str, mesh: Mesh) -> PyTree:
+def shardings_for(axes_tree: PyTree, mode: str, mesh: Mesh,
+                  shapes: Optional[PyTree] = None) -> PyTree:
+    """NamedShardings for an axes tree; with ``shapes`` (a matching tree of
+    arrays or ShapeDtypeStructs) non-divisible dims stay replicated."""
+    if shapes is None:
+        return jax.tree.map(
+            lambda a: NamedSharding(mesh, logical_to_spec(a, mode, mesh)),
+            axes_tree, is_leaf=_IS_AXES)
     return jax.tree.map(
-        lambda a: NamedSharding(mesh, logical_to_spec(a, mode, mesh)),
-        axes_tree, is_leaf=_IS_AXES)
+        lambda a, s: NamedSharding(
+            mesh, logical_to_spec(a, mode, mesh, shape=s.shape)),
+        axes_tree, shapes, is_leaf=_IS_AXES)
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:

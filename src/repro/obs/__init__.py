@@ -16,11 +16,11 @@ from repro.obs.telemetry import (RECORD_TYPES, SCHEMA_VERSION,
                                  JsonlSink, PrettySink, RingSink, Sink,
                                  Telemetry, get_telemetry, set_telemetry,
                                  telemetry_scope)
-from repro.obs.trace import Tracer, fenced_time, jax_profiler_trace
+from repro.obs.trace import Tracer, fenced_time
 
 __all__ = [
     "JsonlSink", "PrettySink", "RingSink", "RECORD_TYPES",
     "SCHEMA_VERSION", "Sink", "Telemetry", "Tracer", "fenced_time",
-    "get_telemetry", "jax_profiler_trace", "meters", "set_telemetry",
+    "get_telemetry", "meters", "set_telemetry",
     "telemetry_scope",
 ]

@@ -147,19 +147,3 @@ def fenced_time(fn: Callable, *args, iters: int = 10, warmup: int = 2,
             tracer.add_event(name, t0, dt, iter=i)
     times.sort()
     return times[len(times) // 2] * 1e6
-
-
-@contextlib.contextmanager
-def jax_profiler_trace(logdir: str) -> Iterator[None]:
-    """Thin wrapper over ``jax.profiler.trace`` (TensorBoard-viewable XLA
-    profile) that degrades to a no-op when the profiler is unavailable
-    (e.g. a second concurrent trace, or a stripped jaxlib)."""
-    import jax
-    try:
-        with jax.profiler.trace(logdir):
-            yield
-    except Exception as e:  # profiler double-start, missing backend, ...
-        import warnings
-        warnings.warn(f"obs.jax_profiler_trace: profiler unavailable "
-                      f"({e}); continuing without an XLA profile")
-        yield

@@ -118,8 +118,6 @@ class Roofline:
 
 def raw_costs(compiled) -> Dict[str, float]:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

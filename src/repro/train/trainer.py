@@ -2,10 +2,11 @@
 checkpoint hooks, metrics.
 
 Works in two regimes:
-  * CPU simulation (tests/examples): no mesh, n simulated nodes as a stacked
-    leading axis on one device.
-  * Mesh execution (launch/train.py, dry-run): state/batch sharded by the
-    logical-axis rules; same code path, jit called with shardings.
+  * No mesh (one device): n nodes as a stacked leading axis on that device.
+  * Mesh execution (launch/train.py on several devices): state and batches
+    are placed by the ``train_data``/``train_pod`` logical-axis rules
+    (models/sharding.py), the node axis split over the mesh's node axes,
+    and every step returns the state on that same placement.
 """
 from __future__ import annotations
 
@@ -16,16 +17,20 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.configs.base import TrainConfig
 from repro.core import topology as topo
+from repro.core.mixing import auto_axes, node_axis_names, node_shard_count
 from repro.core.schedule import make_schedule
 from repro.data import make_stream
+from repro.models import sharding as shd
 from repro.models.model import Model, make_model
 from repro.optim import make_optimizer, make_schedule as make_lr
 from repro.core import algo as algo_lib
-from repro.train.state import TrainState, stack_for_nodes
+from repro.train.state import (TrainState, stack_for_nodes, stacked_axes,
+                               state_axes)
 from repro.train.step import build_train_step
 
 PyTree = Any
@@ -49,6 +54,8 @@ class Trainer:
                 raise ValueError(
                     f"Trainer: fault_schedule built for "
                     f"{fault_schedule.n_nodes} nodes, trainer has {n_nodes}")
+        if mesh is not None:
+            mesh = auto_axes(mesh)
         self.tcfg = tcfg
         self.n_nodes = n_nodes
         self.mesh = mesh
@@ -96,6 +103,35 @@ class Trainer:
         self._sched_live = False   # True once this process advanced the
                                    # schedule (guards the resume reload)
         self._faults_live = False  # same guard for the fault counters
+        self.state_shardings = None
+        self._batch_sharding = None
+        if mesh is not None:
+            self._place_on(mesh)
+
+    def _place_on(self, mesh: jax.sharding.Mesh) -> None:
+        """Shardings of the state (logical-axis rules) and of a batch (node
+        axis over the mesh's node axes)."""
+        dist = self.tcfg.dist
+        k = node_shard_count(mesh, dist.node_axis)
+        if self.n_nodes % k:
+            raise ValueError(
+                f"Trainer: {self.n_nodes} nodes do not split evenly over "
+                f"the mesh's {k} node shards")
+        axes_box: Dict[str, Any] = {}
+
+        def init_axes(key):
+            params, axes_box["axes"] = self.model.init(key)
+            return self._init_tree(params)
+
+        shapes = jax.eval_shape(init_axes, jax.random.PRNGKey(0))
+        st_axes = stacked_axes(axes_box["axes"])
+        tree = state_axes(st_axes, self.tcfg.optimizer.name,
+                          extras=algo_lib.extras_axes(dist, st_axes,
+                                                      axes_box["axes"]))
+        mode = "train_data" if dist.node_axis == "data" else "train_pod"
+        self.state_shardings = shd.shardings_for(tree, mode, mesh, shapes)
+        names = node_axis_names(mesh, dist.node_axis)
+        self._batch_sharding = NamedSharding(mesh, P(names) if names else P())
 
     @property
     def history(self) -> List[Dict[str, float]]:
@@ -106,8 +142,7 @@ class Trainer:
         return ring.records("step") if ring is not None else []
 
     # ------------------------------------------------------------------
-    def init_state(self, key: jax.Array) -> TrainState:
-        params, _axes = self.model.init(key)
+    def _init_tree(self, params: PyTree) -> TrainState:
         params = stack_for_nodes(params, self.n_nodes)
         opt = make_optimizer(self.tcfg.optimizer, per_node=True)
         opt_state = opt.init(params)
@@ -117,6 +152,19 @@ class Trainer:
         extras = algo_lib.init_extras(self.tcfg.dist, params, self.n_nodes)
         return TrainState(params=params, opt_state=opt_state,
                           step=jnp.zeros((), jnp.int32), extras=extras)
+
+    def init_state(self, key: jax.Array) -> TrainState:
+        if self.state_shardings is None:
+            return self._init_tree(self.model.init(key)[0])
+        # built in place: the stacked state never lands on one device
+        return jax.jit(lambda k: self._init_tree(self.model.init(k)[0]),
+                       out_shardings=self.state_shardings)(key)
+
+    def _batch(self, k: int) -> PyTree:
+        batch = self.stream.get_batch(k)
+        if self._batch_sharding is None:
+            return jax.tree.map(jnp.asarray, batch)
+        return jax.device_put(batch, self._batch_sharding)
 
     # ------------------------------------------------------------------
     def _get_step_fn(self, phase: str, shift: int, buf_shift: int = 0):
@@ -135,6 +183,8 @@ class Trainer:
                                   buf_shift=buf_shift,
                                   with_consensus=self.with_consensus,
                                   mesh=self.mesh, fault_hops=hops)
+            if self.state_shardings is not None:
+                fn = _keep_placement(fn, self.state_shardings)
             donate = (0, 3) if self._overlap else (0,)
             self._compiled[key] = jax.jit(fn, donate_argnums=donate)
         return self._compiled[key]
@@ -190,6 +240,9 @@ class Trainer:
         # metrics stay on device until the batched log-boundary fetch
         # repro: allow(RPR001)
         start = int(jax.device_get(state.step))
+        if self.state_shardings is not None:
+            # a restored checkpoint arrives on the host: place it
+            state = jax.device_put(state, self.state_shardings)
         # resume-aware: schedule/lr/data keyed on the
         if start > 0 and not self._sched_live:  # absolute step counter —
             # and a stateful schedule (AGA's period counter) is trajectory
@@ -223,7 +276,7 @@ class Trainer:
             self._buf_shift = self.schedule.gossip_shift_step(
                 start, self.period)
         for k in range(start, start + steps):
-            batch = jax.tree.map(jnp.asarray, self.stream.get_batch(k))
+            batch = self._batch(k)
             # advance() commits stateful schedules (AGA's period counter);
             # phase()/peek_phase() stay pure for dryrun/roofline/logging
             phase = (self.schedule.advance(k) if self.n_nodes > 1
@@ -318,19 +371,11 @@ class Trainer:
         compute.  Uses fresh non-donating jits so ``state`` survives;
         runs with the ambient hub scoped out so the probe rounds do not
         spam ``comm_round`` records."""
-        try:
-            self._measure_occupancy_impl(state, k)
-        except Exception as e:   # calibration is best-effort telemetry
-            import warnings
-            warnings.warn(f"Trainer: occupancy calibration failed ({e}); "
-                          f"continuing without an occupancy record")
-
-    def _measure_occupancy_impl(self, state: TrainState, k: int) -> None:
         from repro.core import mixing
         tcfg = self.tcfg
         spec = tcfg.dist.comm_spec(self.n_nodes, mesh=self.mesh)
         shift = self.schedule.gossip_shift_step(k, self.period)
-        batch = jax.tree.map(jnp.asarray, self.stream.get_batch(k))
+        batch = self._batch(k)
         lr = jnp.asarray(self.lr_fn(k), jnp.float32)
 
         def build(phase):
@@ -430,6 +475,17 @@ class Trainer:
         if os.path.exists(path):
             with open(path) as f:
                 self.fault_schedule.load_state_dict(json.load(f))
+
+
+def _keep_placement(step_fn, state_shardings):
+    """Pin the returned state to the input placement: left to itself the
+    compiler may hand back a replicated node axis after a global average,
+    and the next step would then recompile for the new layout."""
+    def step(state, *args):
+        out = step_fn(state, *args)
+        return (jax.lax.with_sharding_constraint(out[0], state_shardings),
+                *out[1:])
+    return step
 
 
 def quick_train(tcfg: TrainConfig, n_nodes: int, steps: int, *,

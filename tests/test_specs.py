@@ -16,12 +16,7 @@ from repro.models.sharding import logical_to_spec
 
 
 def _mesh(shape=(16, 16), axes=("data", "model")):
-    # jax >= 0.4.36 wants ((name, size), ...) pairs; older releases took
-    # (shape, axes) positionally — support both so the pinned-min CI leg runs.
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 def test_divisible_dims_are_sharded():

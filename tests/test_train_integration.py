@@ -2,7 +2,10 @@
 checkpoint roundtrip is exact; parallel == PGA(full topology) on the real
 model train step."""
 import os
+import subprocess
+import sys
 import tempfile
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +103,94 @@ def test_gossip_nodes_diverge_then_global_resyncs():
     assert cons[3] > cons[0] * 0.9 and cons[3] > 0
     # step 5 = global averaging -> consensus ~0
     assert cons[4] < 1e-8
+
+
+def _run_script(script: str, env_extra=None, timeout: int = 600):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    env.update(env_extra or {})
+    return subprocess.run([sys.executable, "-c", script],
+                          capture_output=True, text=True, env=env,
+                          timeout=timeout)
+
+
+_MESH4_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.configs import (DataConfig, DistConfig, OptimizerConfig,
+                               TrainConfig, get_model_config)
+    from repro.launch.mesh import node_mesh
+    from repro.train import Trainer
+
+    def tcfg(backend):
+        return TrainConfig(
+            model=get_model_config("pga-lm-100m", reduced=True),
+            dist=DistConfig(algorithm="gossip_pga", topology="one_peer_exp",
+                            H=2, comm_backend=backend),
+            optimizer=OptimizerConfig(name="adamw", lr=3e-3,
+                                      schedule="constant", warmup_steps=0),
+            data=DataConfig(non_iid=True), global_batch=8, seq_len=16,
+            log_every=0)
+
+    try:
+        node_mesh(6)
+        raise AssertionError("6 nodes cannot split over 4 devices")
+    except ValueError:
+        pass
+    # launch/train's mesh (Auto axes) and jax.make_mesh's default
+    # (Explicit axes) must both work
+    meshes = {"reference": node_mesh(4),
+              "pallas": jax.make_mesh((4,), ("data",))}
+    final = {}
+    for backend, mesh in meshes.items():
+        tr = Trainer(tcfg(backend), n_nodes=4, mesh=mesh,
+                     with_consensus=True)
+        state = tr.run(tr.init_state(jax.random.PRNGKey(0)), steps=3)
+        for tree in (state.params, state.opt_state):
+            for a in jax.tree.leaves(tree):
+                if a.ndim and a.shape[0] == 4:
+                    assert len(a.sharding.device_set) == 4, a.sharding
+                    assert a.sharding.spec[0] == "data", a.sharding
+        final[backend] = jax.device_get(state.params)
+    for a, b in zip(jax.tree.leaves(final["reference"]),
+                    jax.tree.leaves(final["pallas"])):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    print("MESH4_OK")
+""")
+
+
+def test_trainer_places_state_over_four_devices():
+    """With a 4-device ("data",) mesh the state's node axis is split over
+    the devices on both comm backends, stays split across global rounds,
+    and the two backends agree."""
+    out = _run_script(_MESH4_SCRIPT)
+    assert "MESH4_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-4000:]
+
+
+_CACHE_SCRIPT = textwrap.dedent("""
+    import os, sys
+    import jax, jax.numpy as jnp
+    from repro.launch import mesh
+    assert mesh.use_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
+    jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64))).block_until_ready()
+    del os.environ["JAX_COMPILATION_CACHE_DIR"]
+    path = mesh.use_compile_cache()
+    assert path == str(mesh.CHECKOUT / ".jax_cache"), path
+    assert jax.config.jax_compilation_cache_dir == path
+    print("CACHE_OK")
+""")
+
+
+def test_compile_cache_follows_the_environment(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, entries land there; unset, the
+    entry points point JAX at the checkout's fixed .jax_cache."""
+    cache = tmp_path / "cache"
+    out = _run_script(_CACHE_SCRIPT, {
+        "JAX_COMPILATION_CACHE_DIR": str(cache),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0"})
+    assert "CACHE_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-4000:]
+    assert any(cache.iterdir())
