@@ -69,7 +69,6 @@ the exact collective and re-primes the buffer at the period boundary.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import warnings
@@ -150,11 +149,14 @@ class CommSpec:
 # ---------------------------------------------------------------------------
 # Telemetry hooks (DESIGN.md §2.7): when an ambient obs.Telemetry hub is
 # installed, every round entry point self-reports a `comm_round` record
-# (analytic vs measured wire bytes, phase/shift/backend tags) and wraps
-# itself in a tracer span.  With no hub installed the hooks are a None
-# check — the hot path pays nothing.  Records emitted while *tracing*
-# (inside jit) carry traced=True and appear once per compiled variant;
-# per-executed-round counts come from the trainer's step records.
+# (analytic vs measured wire bytes, phase/shift/backend tags).  With no
+# hub installed the hooks are a None check — the hot path pays nothing.
+# Records emitted while *tracing* (inside jit) carry traced=True and
+# appear once per compiled variant; per-executed-round counts come from
+# the trainer's step records.  Each entry point also runs under a
+# `jax.named_scope` of its role (round, issue, apply, flush): the ops it
+# emits carry that name in their HLO op_name, so a profiler trace shows
+# them on the device clock, hub or no hub.
 # ---------------------------------------------------------------------------
 def _hub():
     try:
@@ -193,14 +195,6 @@ def meter_round(params: PyTree, spec: CommSpec, *, phase: str,
     if tel is not None:
         _meter(tel, params, spec, phase=phase, step=step, role=role,
                wires=wires)
-
-
-def _fence_maybe(handle, out) -> None:
-    """Fence a span on concrete round outputs; inside a jit trace the
-    outputs are tracers (no device work to wait on) — skip."""
-    leaves = jax.tree.leaves(out)
-    if leaves and not isinstance(leaves[0], jax.core.Tracer):
-        handle.fence(leaves)
 
 
 def _check_backend(backend: str, axis: int,
@@ -775,19 +769,15 @@ def _communicate_metered(params: PyTree, spec: CommSpec, *, phase: str,
                          ef_state: Optional[PyTree] = None,
                          seed=0) -> PyTree:
     """:func:`communicate` body + telemetry: one ``comm_round`` record
-    and a ``comm/round`` span per public round (internal identity/exact
-    re-dispatches go straight to ``_communicate_impl`` and never
-    double-report)."""
+    per public round, its ops under the ``round`` scope (internal
+    identity/exact re-dispatches go straight to ``_communicate_impl`` and
+    never double-report)."""
     tel = _hub()
-    if tel is None:
+    if tel is not None:
+        _meter(tel, params, spec, phase=phase, step=step, role="round")
+    with jax.named_scope("round"):
         return _communicate_impl(params, spec, phase=phase, step=step,
                                  axis=axis, ef_state=ef_state, seed=seed)
-    _meter(tel, params, spec, phase=phase, step=step, role="round")
-    with tel.span("comm/round", phase=phase, shift=int(step)) as sp:
-        out = _communicate_impl(params, spec, phase=phase, step=step,
-                                axis=axis, ef_state=ef_state, seed=seed)
-        _fence_maybe(sp, out)
-    return out
 
 
 def _communicate_impl(params: PyTree, spec: CommSpec, *, phase: str,
@@ -1315,14 +1305,13 @@ def start_round(params: PyTree, spec: CommSpec, *,
     :func:`finish_round` applies must be the one of the issuing step
     (pass the capture step's ``gossip_shift_step`` as ``step=``).
     """
-    tel = _hub()
-    if tel is None:
-        return _start_round_impl(params, spec, ef_state=ef_state, seed=seed)
-    with tel.span("comm/issue") as sp:
+    with jax.named_scope("issue"):
         out = _start_round_impl(params, spec, ef_state=ef_state, seed=seed)
-        _fence_maybe(sp, out)
-    _meter(tel, params, spec, phase="gossip", step=0, role="issue",
-           wires=out[0].get("wire") if isinstance(out[0], dict) else None)
+    tel = _hub()
+    if tel is not None:
+        _meter(tel, params, spec, phase="gossip", step=0, role="issue",
+               wires=out[0].get("wire") if isinstance(out[0], dict)
+               else None)
     return out
 
 
@@ -1368,17 +1357,13 @@ def finish_round(params: PyTree, round_state, spec: CommSpec, *,
     :func:`overlap_flush`.
     """
     tel = _hub()
-    if tel is None:
+    if tel is not None:
+        _meter(tel, params, spec, phase="gossip", step=step, role="apply",
+               wires=round_state.get("wire")
+               if isinstance(round_state, dict) else None)
+    with jax.named_scope("apply"):
         return _finish_round_impl(params, round_state, spec, step=step,
                                   block_d=block_d, interpret=interpret)
-    _meter(tel, params, spec, phase="gossip", step=step, role="apply",
-           wires=round_state.get("wire")
-           if isinstance(round_state, dict) else None)
-    with tel.span("comm/apply", shift=int(step)) as sp:
-        out = _finish_round_impl(params, round_state, spec, step=step,
-                                 block_d=block_d, interpret=interpret)
-        _fence_maybe(sp, out)
-    return out
 
 
 def _finish_round_impl(params: PyTree, round_state, spec: CommSpec, *,
@@ -1429,9 +1414,7 @@ def overlap_flush(params: PyTree, spec: CommSpec, *, phase: str,
     tel = _hub()
     if tel is not None:
         _meter(tel, params, spec, phase=phase, step=step, role="flush")
-    span = (tel.span("comm/flush", phase=phase) if tel is not None
-            else contextlib.nullcontext())
-    with span:
+    with jax.named_scope("flush"):
         out = _communicate_impl(params, spec, phase=phase, step=step,
                                 axis=axis, ef_state=ef_state, seed=seed)
         if spec.compressor is not None \

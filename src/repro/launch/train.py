@@ -99,8 +99,13 @@ def main() -> None:
                          "fault + checkpoint events")
     ap.add_argument("--trace", default="",
                     help="save a Chrome-trace-event timeline of the run's "
-                         "host spans to this path (load in Perfetto / "
-                         "chrome://tracing)")
+                         "host spans (train/step, train/input, train/log) "
+                         "to this path (load in Perfetto / "
+                         "chrome://tracing); on its own clock.  Under a "
+                         "jax.profiler trace the same spans land on the "
+                         "profiler's host plane, on the device clock, "
+                         "beside the step's named scopes (fwd_bwd, "
+                         "optimizer, monitor, round)")
     ap.add_argument("--trace-fence", action="store_true",
                     help="block_until_ready at span exits so spans measure "
                          "device time instead of async dispatch time "

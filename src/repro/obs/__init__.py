@@ -7,8 +7,8 @@ span tracing with Chrome-trace export, and comm-round byte meters.
     with obs.telemetry_scope(tel):
         ...                         # mixing rounds self-report comm_round
         tel.emit("step", step=k, phase="gossip", loss=0.7)
-        with tel.span("comm/issue") as sp:
-            sp.fence(mixing.start_round(...))
+        with tel.span("train/step", step=k) as sp:
+            sp.fence(step_fn(state, batch, lr))
     tel.tracer.save("trace.json")   # load in Perfetto
 """
 from repro.obs import meters

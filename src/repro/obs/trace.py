@@ -2,7 +2,7 @@
 Chrome-trace-event export (DESIGN.md §2.7).
 
 A :class:`Tracer` collects ``"X"`` (complete) events from ``with
-tracer.span("comm/issue")`` blocks.  Spans measure *host* wall-clock by
+tracer.span("train/step")`` blocks.  Spans measure *host* wall-clock by
 default — under JAX's async dispatch that is dispatch time, not device
 time.  Fencing closes the gap: ``span(...)`` yields a handle whose
 ``fence(value)`` registers a jax value to ``block_until_ready`` at span
@@ -10,6 +10,11 @@ exit, either always (``fence="always"``) or only when the tracer was
 built with ``fence=True`` (the ``--trace-fence`` flag; ``fence="auto"``,
 the default).  Unfenced spans are nearly free; fenced spans serialize
 the pipeline they measure — that trade is the point of the flag.
+
+Each span is also a ``jax.profiler.TraceAnnotation`` of the same name and
+args, so under a ``jax.profiler`` trace it lands on the host plane, on
+the device trace's clock.  Outside a profiler trace an annotation records
+nothing.
 
 :func:`to_chrome` emits the Chrome trace-event JSON format
 (``{"traceEvents": [{"ph": "X", "ts": µs, "dur": µs, ...}]}``), which
@@ -56,10 +61,13 @@ class _SpanHandle:
 
 class Tracer:
     """Collects timed span events; thread-safe; export via
-    :meth:`to_chrome` / :meth:`save`."""
+    :meth:`to_chrome` / :meth:`save`.  A subclass that overrides
+    :meth:`span` owns its profiler annotation: the base span then opens
+    none, so a span is never annotated twice."""
 
     def __init__(self, fence: bool = False, max_events: int = MAX_EVENTS):
         self.fence = fence
+        self._annotate = type(self).span is Tracer.span
         self.events: deque = deque(maxlen=max_events)
         self._origin = time.perf_counter()
         self._lock = threading.Lock()
@@ -76,24 +84,30 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, **args) -> Iterator[_SpanHandle]:
         """Time a block as one complete ("X") event.  ``args`` become the
-        event's ``args`` payload (shown on click in Perfetto)."""
+        event's ``args`` payload (shown on click in Perfetto) and the
+        profiler annotation's."""
         handle = _SpanHandle(dict(args))
+        annotation = contextlib.nullcontext()
+        if self._annotate:
+            import jax
+            annotation = jax.profiler.TraceAnnotation(name, **args)
         t0 = time.perf_counter()
-        try:
-            yield handle
-        finally:
-            if handle.value is not None and (
-                    handle.mode == "always" or self.fence):
-                import jax
-                jax.block_until_ready(handle.value)
-            t1 = time.perf_counter()
-            self.events.append({
-                "name": name,
-                "t0": t0 - self._origin,
-                "dur": t1 - t0,
-                "tid": self._tid(),
-                "args": handle.args,
-            })
+        with annotation:
+            try:
+                yield handle
+            finally:
+                if handle.value is not None and (
+                        handle.mode == "always" or self.fence):
+                    import jax
+                    jax.block_until_ready(handle.value)
+                t1 = time.perf_counter()
+                self.events.append({
+                    "name": name,
+                    "t0": t0 - self._origin,
+                    "dur": t1 - t0,
+                    "tid": self._tid(),
+                    "args": handle.args,
+                })
 
     def add_event(self, name: str, t0: float, dur: float,
                   **args) -> None:

@@ -314,63 +314,82 @@ def build_train_step(model: Model, tcfg: TrainConfig, n_nodes: int, *,
         return algo_lib.wrap_mixed(mixed, has_payload), fused_consensus
 
     # -- the one step body -------------------------------------------------
+    # Every op of the step falls under one of four sibling named scopes —
+    # fwd_bwd, optimizer, monitor, round — so a profiler trace splits the
+    # step's device time by its HLO op_name (DESIGN.md §2.7).  A scope is
+    # metadata only: the optimized program is the same without it.
     def _core(state: TrainState, batch: PyTree, lr: jax.Array,
               comm_buf=None, W=None, active=None
               ) -> Tuple[TrainState, Dict[str, jax.Array], Any]:
         extras = dict(state.extras)
-        if tcfg.microbatches > 1:
-            grads, metrics = accum_grad_fn(state.params, batch)
-        else:
-            grads, metrics = grad_fn(state.params, batch)
+        with jax.named_scope("fwd_bwd"):
+            if tcfg.microbatches > 1:
+                grads, metrics = accum_grad_fn(state.params, batch)
+            else:
+                grads, metrics = grad_fn(state.params, batch)
         if mode == "push":
-            af = active.astype(jnp.float32)
-            grads = jax.tree.map(
-                lambda g: g * af.reshape((n_nodes,) + (1,) * (g.ndim - 1)),
-                grads)
+            with jax.named_scope("optimizer"):
+                af = active.astype(jnp.float32)
+                grads = jax.tree.map(
+                    lambda g: g * af.reshape(
+                        (n_nodes,) + (1,) * (g.ndim - 1)), grads)
         if with_consensus:
             metrics = dict(metrics)
-            metrics["grad_norm"] = _grad_global_norm(grads)
-        if tcfg.optimizer.grad_clip:
-            grads = clip_by_global_norm(grads, tcfg.optimizer.grad_clip)
-        upd, extras = algo.pre_update(extras, grads)
-        extras = dict(extras)
-        params_half, opt_state = opt.update(upd, state.opt_state,
-                                            state.params, lr)
+            with jax.named_scope("monitor"):
+                metrics["grad_norm"] = _grad_global_norm(grads)
+        with jax.named_scope("optimizer"):
+            if tcfg.optimizer.grad_clip:
+                grads = clip_by_global_norm(grads, tcfg.optimizer.grad_clip)
+            upd, extras = algo.pre_update(extras, grads)
+            extras = dict(extras)
+            params_half, opt_state = opt.update(upd, state.opt_state,
+                                                state.params, lr)
+            if mode == "push":
+                params_half = freeze_dropped(params_half, state.params,
+                                             active)
+                opt_state = freeze_dropped(opt_state, state.opt_state,
+                                           active)
         sctx = algo_lib.StepContext(dist=dist, n_nodes=n_nodes, lr=lr)
         fused_consensus = None
         new_buf = comm_buf
-        if mode == "push":
-            params_half = freeze_dropped(params_half, state.params, active)
-            opt_state = freeze_dropped(opt_state, state.opt_state, active)
-            mixed = _push_round(extras, params_half, state.step, W, active)
-            new_params, extras = algo.post_round(extras, mixed, phase, sctx)
-        elif mode == "overlap":
-            mixed, new_buf, owned_params = _overlap_round(
-                extras, params_half, state.step, comm_buf, sctx)
-            if owned_params is not None:
-                new_params = owned_params
-            else:
+        with jax.named_scope("round"):
+            if mode == "push":
+                mixed = _push_round(extras, params_half, state.step, W,
+                                    active)
                 new_params, extras = algo.post_round(extras, mixed, phase,
                                                      sctx)
-        else:
-            mixed, fused_consensus = _sync_round(extras, params_half,
-                                                 state.step)
-            new_params, extras = algo.post_round(extras, mixed, phase, sctx)
+            elif mode == "overlap":
+                mixed, new_buf, owned_params = _overlap_round(
+                    extras, params_half, state.step, comm_buf, sctx)
+                if owned_params is not None:
+                    new_params = owned_params
+                else:
+                    new_params, extras = algo.post_round(extras, mixed,
+                                                         phase, sctx)
+            else:
+                mixed, fused_consensus = _sync_round(extras, params_half,
+                                                     state.step)
+                new_params, extras = algo.post_round(extras, mixed, phase,
+                                                     sctx)
         metrics = dict(metrics)
-        if mode == "push":
-            # the checkable invariant: Σw = n for every column-stochastic
-            # round, every fault pattern (DESIGN.md §2.5)
-            new_w = extras["push_weight"]
-            metrics["mass"] = jnp.sum(new_w.astype(jnp.float32))
-            if with_consensus:
-                metrics["consensus"] = consensus_distance(
-                    debias(new_params, new_w))
-        elif with_consensus:
-            metrics["consensus"] = (fused_consensus
-                                    if fused_consensus is not None
-                                    else consensus_distance(new_params))
+        with jax.named_scope("monitor"):
+            if mode == "push":
+                # the checkable invariant: Σw = n for every
+                # column-stochastic round, every fault pattern
+                # (DESIGN.md §2.5)
+                new_w = extras["push_weight"]
+                metrics["mass"] = jnp.sum(new_w.astype(jnp.float32))
+                if with_consensus:
+                    metrics["consensus"] = consensus_distance(
+                        debias(new_params, new_w))
+            elif with_consensus:
+                metrics["consensus"] = (fused_consensus
+                                        if fused_consensus is not None
+                                        else consensus_distance(new_params))
+        with jax.named_scope("optimizer"):
+            next_step = state.step + 1
         new_state = TrainState(params=new_params, opt_state=opt_state,
-                               step=state.step + 1, extras=extras)
+                               step=next_step, extras=extras)
         return new_state, metrics, new_buf
 
     # -- historical per-mode signatures ------------------------------------

@@ -276,7 +276,8 @@ class Trainer:
             self._buf_shift = self.schedule.gossip_shift_step(
                 start, self.period)
         for k in range(start, start + steps):
-            batch = self._batch(k)
+            with self.telemetry.span("train/input", step=k):
+                batch = self._batch(k)
             # advance() commits stateful schedules (AGA's period counter);
             # phase()/peek_phase() stay pure for dryrun/roofline/logging
             phase = (self.schedule.advance(k) if self.n_nodes > 1
@@ -303,7 +304,8 @@ class Trainer:
                     step_fn = self._get_step_fn(phase, shift)
                     state, metrics = step_fn(state, batch, lr)
                 # --trace-fence: serialize the pipeline so the span is
-                # device time, not async dispatch time
+                # device time, not async dispatch time.  Only this span
+                # fences: a fenced span marks one step's completion
                 sp.fence(metrics["loss"])
             # lazily: the schedule holds the DEVICE scalar and
             # materializes it only at period boundaries (explicit
@@ -316,7 +318,8 @@ class Trainer:
                 # in-flight round before its collective (DESIGN.md §2.6)
                 self.telemetry.emit("flush", step=k, phase=phase)
             if log_every and (k % log_every == 0 or k == steps - 1):
-                self._log_boundary(k, phase, t0)
+                with self.telemetry.span("train/log", step=k):
+                    self._log_boundary(k, phase, t0)
                 mo = self.measure_occupancy
                 if mo is None:
                     mo = any(isinstance(s, obs.JsonlSink)

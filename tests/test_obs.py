@@ -2,8 +2,10 @@
 trace export, comm-round byte meters vs the analytic cost model, overlap
 issue/apply accounting, fault events in the stream, and the
 zero-per-step-host-sync regression on the Trainer hot path."""
+import contextlib
 import json
 import os
+import re
 import tempfile
 
 import jax
@@ -179,22 +181,106 @@ def test_comm_round_meter_noop_without_hub():
 # ---------------------------------------------------------------------------
 # Overlap: issue/apply records iff comm_overlap; occupancy reported
 # ---------------------------------------------------------------------------
+def _dumped_scopes(path) -> set:
+    """Every name-stack component of the ops in the modules JAX dumped to
+    ``path`` (``jax_dump_ir_to``)."""
+    names = set()
+    for f in os.listdir(path):
+        with open(os.path.join(path, f)) as fh:
+            for loc in re.findall(r'loc\("(jit\([^"]*)"', fh.read()):
+                names.update(loc.split("/"))
+    return names
+
+
 @pytest.mark.parametrize("overlap", [False, True])
-def test_overlap_issue_apply_iff_comm_overlap(overlap):
+def test_overlap_issue_apply_iff_comm_overlap(overlap, tmp_path):
     loss_fn, grad_fn, d = _quadratic()
     tel = obs.Telemetry(sinks=[obs.RingSink()])
-    simulate(algorithm="gossip_pga", grad_fn=grad_fn, loss_fn=loss_fn,
-             x0=jnp.zeros(d), n=4, steps=8, lr=0.05, topology="ring",
-             H=4, eval_every=4, overlap=overlap, telemetry=tel)
+    prev = jax.config.values["jax_dump_ir_to"]
+    jax.config.update("jax_dump_ir_to", str(tmp_path))
+    try:
+        simulate(algorithm="gossip_pga", grad_fn=grad_fn, loss_fn=loss_fn,
+                 x0=jnp.zeros(d), n=4, steps=8, lr=0.05, topology="ring",
+                 H=4, eval_every=4, overlap=overlap, telemetry=tel)
+    finally:
+        jax.config.update("jax_dump_ir_to", prev)
     roles = {r["role"] for r in tel.ring().records("comm_round")}
-    span_names = {e["name"] for e in tel.tracer.events}
+    scopes = _dumped_scopes(tmp_path)
     if overlap:
         assert {"issue", "apply"} <= roles
-        assert {"comm/issue", "comm/apply"} <= span_names
+        # the float32 issue captures the iterate itself and emits no op;
+        # the apply and the period boundary's flush do
+        assert {"apply", "flush"} <= scopes
     else:
         assert "issue" not in roles and "apply" not in roles
-        assert "comm/issue" not in span_names
-        assert "round" in roles
+        assert not {"issue", "apply", "flush"} & scopes
+        assert "round" in roles and "round" in scopes
+
+
+# ---------------------------------------------------------------------------
+# Named scopes of the train step: every device op under one of four
+# ---------------------------------------------------------------------------
+STEP_SCOPES = ("fwd_bwd", "optimizer", "monitor", "round")
+STEP_MODES = {"sync": {}, "overlap": {"comm_overlap": True},
+              "push_sum": {"push_sum": True},
+              "pallas_fused": {"comm_backend": "pallas"}}
+
+
+def _scope_of(op_name: str) -> str:
+    return next((p for p in re.split(r"[/;]", op_name)
+                 if p in STEP_SCOPES), "")
+
+
+def _step_hlo(mode: str, phase: str) -> str:
+    """Optimized HLO text of the trainer's compiled step.  A bfloat16 wire
+    makes the overlapped issue a cast, so it emits ops of its own."""
+    tcfg = _tcfg(comm_dtype="bfloat16", **STEP_MODES[mode])
+    tr = Trainer(tcfg, n_nodes=4, with_consensus=True)
+    state = tr.init_state(jax.random.PRNGKey(0))
+    args = [state, tr._batch(0), jnp.asarray(0.05, jnp.float32)]
+    if mode == "overlap":
+        spec = tcfg.dist.comm_spec(4)
+        args.append(mixing.start_round(state.params, spec)[0])
+    if mode == "push_sum":
+        args.extend(tr._push_round(phase, 0, 0))
+    step = tr._get_step_fn(phase, 0, buf_shift=0)
+    return step.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("mode", list(STEP_MODES))
+@pytest.mark.parametrize("phase", ["gossip", "global"])
+def test_train_step_ops_carry_the_step_scopes(phase, mode):
+    ops = re.findall(r'^\s*(?:ROOT )?%\S+ = .*op_name="(jit\(\w+\)/[^"]*)"',
+                     _step_hlo(mode, phase), re.M)
+    scopes = {_scope_of(n) for n in ops}
+    assert {"fwd_bwd", "optimizer", "round"} <= scopes
+    if mode != "pallas_fused":
+        # the fused kernel emits the consensus residual inside the round,
+        # and XLA merges the grad-norm monitor with the clip's equal norm
+        assert "monitor" in scopes
+    parts = {p for n in ops for p in re.split(r"[/;]", n)}
+    if mode == "overlap" and phase == "gossip":
+        assert {"issue", "apply"} <= parts
+    elif mode == "overlap":
+        assert "flush" in parts and "apply" not in parts
+    else:
+        assert not {"issue", "apply", "flush"} & parts
+    # a backward op sits under the scope of its forward
+    assert any(n.split("/")[1] == "fwd_bwd" and "transpose(" in n
+               for n in ops)
+
+
+def test_step_scopes_leave_the_optimized_program_unchanged(monkeypatch):
+    def strip(text):
+        return [re.sub(r", metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines()
+                if re.match(r"\s*(ROOT |%|ENTRY|HloModule|\})", line)]
+
+    scoped = strip(_step_hlo("sync", "gossip"))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = strip(_step_hlo("sync", "gossip"))
+    assert scoped == plain
 
 
 def test_trainer_overlap_occupancy_record():
