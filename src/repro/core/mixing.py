@@ -181,8 +181,38 @@ def _meter(tel, params: PyTree, spec: CommSpec, *, phase: str, step: int,
         backend=spec.backend, sharded=sharded,
         comm_dtype=spec.comm_dtype, compressor=spec.compressor,
         global_compressor=spec.global_compressor, model_shards=km,
-        wires=wires, role=role)
+        wires=wires, role=role,
+        staged_bytes=_staged_bytes(
+            params, phase=phase, backend=spec.backend, sharded=sharded,
+            compressor=spec.compressor,
+            global_compressor=spec.global_compressor,
+            leaf_threshold=spec.leaf_threshold))
     tel.emit("comm_round", **fields)
+
+
+def _staged_bytes(params: PyTree, *, phase: str, backend: str,
+                  sharded: bool, compressor=None, global_compressor=None,
+                  leaf_threshold: Optional[int] = None,
+                  weight: Optional[jax.Array] = None) -> int:
+    """Per-node bytes of a pallas round that go through a packed ``(n, D)``
+    staging buffer: the leaves below the dispatch threshold on the stacked
+    uncompressed path; every leaf where the round packs the whole tree
+    (the sharded path, a lossy global codec's collective); none where a
+    lossy gossip codec mixes leaf by leaf, or on the reference backend.
+    A push-sum ``weight`` column rides the buffer beside the leaves."""
+    from repro.kernels import mixing_pallas
+    if backend != "pallas" or phase == "none":
+        return 0
+    tree = params if weight is None else {"x": params, "w": weight}
+    every_leaf = mixing_pallas.staged_bytes(tree, float("inf"))
+    if sharded:
+        return every_leaf
+    if phase in ("global", "pod_avg") and global_compressor is not None:
+        if global_compressor.lossy:
+            return every_leaf
+    elif compressor is not None and compressor.lossy:
+        return 0
+    return mixing_pallas.staged_bytes(tree, leaf_threshold)
 
 
 def meter_round(params: PyTree, spec: CommSpec, *, phase: str,
@@ -1700,6 +1730,10 @@ def communicate_push_sum(params: PyTree, weight: jax.Array, *,
                          else "none"),
             measured_bytes=int(sum(sizes)) * int(elem),
             analytic_bytes=None,
+            staged_bytes=_staged_bytes(
+                params, phase="push_sum", backend=backend, sharded=sharded,
+                compressor=compressor, leaf_threshold=leaf_threshold,
+                weight=weight),
             traced=bool(leaves)
             and isinstance(leaves[0], jax.core.Tracer))
 

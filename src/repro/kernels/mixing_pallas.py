@@ -5,38 +5,50 @@ The reference path in :mod:`repro.core.mixing` applies the gossip round as a
 chain of unfused jnp ops: the SGD half-step ``x − γg`` is one pass over HBM,
 then every circulant shift term ``w_s · roll(x, s)`` re-reads the parameters,
 then the weighted sum writes them back — ``2 + |shifts|`` HBM round-trips per
-round.  Here the whole round is one ``pallas_call``:
+round.  Here each leaf's round is one ``pallas_call`` that reads the leaf
+and writes it back once:
 
-* leaves *below* ``leaf_threshold`` per-node elements are flattened and
-  concatenated into a single ``(n, D)`` node-major matrix, so one kernel
-  covers the long tail of small parameters; leaves *at or above* the
-  threshold get their own kernel dispatch on ``leaf.reshape(n, -1)`` and
-  never touch the concatenation staging buffer.  Every ``pallas_call``
-  aliases its packed input with the mixed output
-  (``input_output_aliases``), so inside a jitted caller (train step,
-  simulator) XLA reuses the staging buffer in place instead of allocating
-  and copying a second ``(n, D)`` output — the aliasing contract is that
-  the packed matrix is consumed by the kernel and must not be read again
-  (DESIGN.md §2.1);
-* the grid walks ``D`` in ``block_d`` columns; each step loads an
-  ``(n, block_d)`` tile into VMEM exactly once, applies the half-step, the
-  mix, and (optionally) the consensus residual in-register, and writes the
-  tile back once — one HBM round-trip total;
-* the circulant mix itself runs as an ``(n, n) @ (n, block_d)`` matmul on the
-  MXU.  The node count is tiny (n ≤ 32), so the dense circulant factor lives
-  in VMEM for the whole kernel; the "never materialize W" rule (DESIGN.md
-  §2.1) is about the *sharded production path*, where W would be an n×n
-  matrix of cross-chip traffic — inside a fused single-chip kernel the n×n
-  factor is the cheapest possible encoding.
+* leaves *at or above* ``leaf_threshold`` per-node elements are mixed in
+  their own layout.  The kernel takes a free view of the leaf: its dims
+  in the order the device's default layout keeps them in memory, each
+  run of dims above the two tiled minor ones merged.  On a v5e
+  ``[n, 12, 768, 3072]`` stays as it is, ``[n, 12, 768, 12, 64]`` is
+  mixed as ``(n, 144, 64, 768)`` and ``[n, 50257, 768]`` as
+  ``(50257, n, 768)``, its node axis the second-minor dim.  No reshape to
+  ``(n, D)``, pad, slice or relayout copy lies between a large leaf and
+  its kernel.  The kernel returns the leaf node-leading: in place where
+  the node axis leads in memory, else moved first in VMEM (XLA then
+  copies it into the leaf's layout);
+* leaves *below* the threshold are flattened and concatenated into one
+  ``(n, D)`` node-major staging matrix, so one kernel covers the long tail
+  of small parameters (``block_d`` columns a step);
+* the block a grid step moves is derived from the leaf's shape: whole
+  minor dims where they fit, about ``_BLOCK_BYTES`` a step, the grid a
+  ``pl.cdiv`` of each blocked dim.  The edge block of a ragged dim is
+  masked: its rows count neither in the residual nor in x̄ (their writes
+  are dropped);
+* every ``pallas_call`` whose x input is node-leading aliases it with
+  the mixed output (``input_output_aliases``), so inside a jitted caller
+  (train step, simulator) XLA updates the leaf or the staging buffer in
+  place — the aliasing contract is that the kernel's input is consumed
+  and must not be read again (DESIGN.md §2.1);
+* the mix over the node axis is an ``(n, n) @ (n, block_d)`` MXU dot in
+  the packed group, whose nodes sit in a tile's sublanes.  A large leaf's
+  view keeps its node axis untiled, which Mosaic cannot contract on the
+  MXU, so there it runs on the VPU: ``d`` and ``M`` are scalars in SMEM
+  (n ≤ 32), each node's row is ``Σ_j M_ij · cast(x_j)`` summed in node
+  order, plus the uncast self term ``d_i · x_i``, all in fp32
+  (interpreted off the chip, it stays the dot: see ``_dot_chunk``).
 
 Three public entry points, one kernel body:
 
 ``fused_step_mix``   — ``W · (x − γg)`` (γ, g optional → plain ``W·x``)
 ``global_average`` / ``pod_average`` — the same kernel with W = 𝟙𝟙ᵀ/n or its
                        pod-block-diagonal variant (the PGA / Hier-PGA rounds)
-``mix_residual``     — additionally emits ``x̄`` and the consensus distance
-                       ``Σ_i ‖x_i − x̄‖²`` of the *mixed* iterate, so eval
-                       loops stop re-reading the parameters they just wrote
+``mix_residual``     — additionally emits ``x̄`` (unless asked not to) and
+                       the consensus distance ``Σ_i ‖x_i − x̄‖²`` of the
+                       *mixed* iterate, so eval loops stop re-reading the
+                       parameters they just wrote
 
 Wire-dtype ("orthogonal quantization") semantics match the reference: for
 gossip rounds the *self* term stays in the storage dtype and only neighbor
@@ -78,6 +90,11 @@ KERNEL_PHASES = ("gossip", "global", "pod_avg")
 # dispatch instead of riding the concatenation staging buffer
 # (DistConfig.pallas_leaf_threshold overrides per run).
 LEAF_DISPATCH_THRESHOLD = 262_144
+
+# Bytes of fp32 x a grid step of a large leaf's kernel loads, over all
+# nodes (0.5 MiB a node at n = 4).  In and out, double-buffered, plus the
+# body's fp32 temporaries stay inside v5e's 16 MiB default scoped VMEM.
+_BLOCK_BYTES = 2 << 20
 
 
 # Every kernel matmul is an fp32 mix like the reference backend's: Mosaic's
@@ -218,125 +235,345 @@ def flatten_nodes_sharded(tree: PyTree, k_model: int
 # ---------------------------------------------------------------------------
 # Kernel body (shared by all entry points)
 # ---------------------------------------------------------------------------
-def _mix_kernel(*refs, with_g: bool, with_residual: bool, wire: bool):
-    """One grid step: load an (n, bd) tile, fuse half-step + mix (+ residual).
+def _dot_chunk(interpret: bool, block_d: int) -> int:
+    """Columns per ``jnp.dot`` of a large leaf's node mix, or 0 for the
+    VPU node sum (the packed group always takes the dot).
 
-    Ref order: [gamma?, x, g?, d, M] then outputs [o, xbar?, r?].
+    Compiled for the chip, the mix is the VPU sum (Mosaic has no matmul
+    over a leading axis).  Interpreted on a CPU it stays the
+    ``(n, n) @ (n, ≤ block_d)`` dot of the packed kernel: XLA:CPU picks a
+    dot's summation order (and FMA use) by the operand shapes and the host
+    CPU, so only the same dot on the same widths keeps the CPU goldens
+    bitwise on every host.  No one VPU summation order stands in for it:
+    on one AVX-512 host a node-order sum changes one pallas golden
+    trajectory of 26 and a pairwise tree 21, while on another host the
+    pairwise tree matched the dot and the node order did not."""
+    return block_d if interpret else 0
+
+
+def _mix_kernel(*refs, ax: int, with_g: bool, with_residual: bool,
+                with_xbar: bool, wire: bool, dims: Tuple[int, ...],
+                block: Tuple[int, ...], dot_chunk: int):
+    """One grid step: load a tile, fuse half-step + mix (+ residual).
+
+    The x (and g) tile has the node axis whole at position ``ax``; the
+    mixed tile, x̄ and everything between are node-leading.  ``dims`` /
+    ``block`` are the non-node dims of the array and of its tile.  A dim
+    that the tile does not divide is ragged: its out-of-range rows are
+    masked out of the residual, and Pallas drops their writes.
+
+    Ref order: [gamma?, d, M, x, g?] then outputs [o, xbar?, r?].
     """
-    idx = 0
-    if with_g:
-        gamma_ref = refs[idx]; idx += 1
-    x_ref = refs[idx]; idx += 1
-    if with_g:
-        g_ref = refs[idx]; idx += 1
-    d_ref = refs[idx]; idx += 1
-    m_ref = refs[idx]; idx += 1
-    o_ref = refs[idx]; idx += 1
-    if with_residual:
-        xbar_ref = refs[idx]; idx += 1
-        r_ref = refs[idx]; idx += 1
+    refs = list(refs)
+    gamma_ref = refs.pop(0) if with_g else None
+    d_ref, m_ref, x_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+    g_ref = refs.pop(0) if with_g else None
+    o_ref = refs.pop(0)
+    xbar_ref = refs.pop(0) if with_residual and with_xbar else None
+    r_ref = refs.pop(0) if with_residual else None
+    n = x_ref.shape[ax]
 
-    x = x_ref[...].astype(jnp.float32)                       # (n, bd)
+    def nodes_first(ref):
+        return jnp.moveaxis(ref[...].astype(jnp.float32), ax, 0)
+
+    x = nodes_first(x_ref)                                   # (n, *block)
     if with_g:
-        x = x - gamma_ref[0, 0] * g_ref[...].astype(jnp.float32)
+        x = x - gamma_ref[0, 0] * nodes_first(g_ref)
     # wire-dtype cast applies to the M term only: neighbor traffic for gossip
     # (d carries the uncast self term), everything for averages (d = 0)
     onwire = x.astype(jnp.bfloat16).astype(jnp.float32) if wire else x
-    mixed = jnp.dot(m_ref[...], onwire, preferred_element_type=jnp.float32,
-                    precision=_FP32)
-    mixed = mixed + d_ref[...] * x
+    if dot_chunk:
+        # every dot as wide as the packed kernel's tiles were, zero padded
+        w2, x2 = onwire.reshape(n, -1), x.reshape(n, -1)
+        cols = w2.shape[1]
+        c = min(dot_chunk, int(np.prod(dims, dtype=np.int64)))
+        pad = ((0, 0), (0, -cols % c))
+        w2, x2 = jnp.pad(w2, pad), jnp.pad(x2, pad)
+        d2 = d_ref[...].reshape(n, 1)
+        parts = [jnp.dot(m_ref[...], w2[:, k:k + c],
+                         preferred_element_type=jnp.float32, precision=_FP32)
+                 + d2 * x2[:, k:k + c]
+                 for k in range(0, w2.shape[1], c)]
+        mixed = jnp.concatenate(parts, axis=1)[:, :cols].reshape(x.shape)
+    else:
+        # row i = Σ_j M_ij · cast(x_j) in node order, then the self term
+        rows = []
+        for i in range(n):
+            acc = m_ref[i, 0] * onwire[0:1]
+            for j in range(1, n):
+                acc = acc + m_ref[i, j] * onwire[j:j + 1]
+            rows.append(acc + d_ref[i] * x[i:i + 1])
+        mixed = jnp.concatenate(rows, axis=0)
     o_ref[...] = mixed.astype(o_ref.dtype)
 
     if with_residual:
-        xbar = jnp.mean(mixed, axis=0, keepdims=True)        # (1, bd)
-        xbar_ref[...] = xbar.astype(xbar_ref.dtype)
+        xbar = jnp.mean(mixed, axis=0, keepdims=True)        # (1, *block)
+        if with_xbar:
+            xbar_ref[...] = xbar.astype(xbar_ref.dtype)
+        sq = jnp.square(mixed - xbar)
+        inside = None
+        for k, (size, b) in enumerate(zip(dims, block)):
+            if size % b:
+                idx = pl.program_id(k) * b + jax.lax.broadcasted_iota(
+                    jnp.int32, xbar.shape, k + 1)
+                ok = idx < size
+                inside = ok if inside is None else inside & ok
+        if inside is not None:
+            sq = jnp.where(inside, sq, 0.0)
 
-        @pl.when(pl.program_id(0) == 0)
+        @pl.when(functools.reduce(
+            jnp.logical_and, [pl.program_id(k) == 0 for k in range(len(dims))]))
         def _init():
             r_ref[0, 0] = 0.0
 
-        r_ref[0, 0] += jnp.sum(jnp.square(mixed - xbar))
+        r_ref[0, 0] += jnp.sum(sq)
+
+
+@functools.lru_cache(maxsize=None)
+def _memory_order(shape: Tuple[int, ...], dtype) -> Tuple[int, ...]:
+    """A leaf's dims, major first, in the default layout of the default
+    device — the layout a jit's parameters and results take.  XLA:TPU
+    permutes dims so that the two tiled minor ones pad least: a
+    ``[4, 50257, 768]`` array lies in memory as ``(50257, 4, 768)``, a
+    ``[4, 12, 768, 12, 64]`` one as ``(4, 12, 12, 64, 768)``."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return tuple(range(len(shape)))
+    lay = dev.client.get_default_layout(np.dtype(dtype), shape, dev)
+    return tuple(lay._xla_layout().minor_to_major()[::-1])
+
+
+def _leaf_view(order: Tuple[int, ...], shape: Tuple[int, ...]
+               ) -> Tuple[Tuple[int, ...], int]:
+    """The view a large leaf is mixed in, and its node axis: the leaf's
+    dims in memory ``order``, with each run of dims above the two tiled
+    minor ones merged, the node axis kept apart.  Transposing to ``order``
+    and merging those runs moves no byte, so the view is free."""
+    mem = [shape[p] for p in order]
+    node, nd = order.index(0), len(shape)
+    view: list = []
+    ax, prev_free = 0, False
+    for k, size in enumerate(mem):
+        free = k != node and k < nd - 2
+        if free and prev_free:
+            view[-1] *= size
+        else:
+            view.append(size)
+        if k == node:
+            ax = len(view) - 1
+        prev_free = free
+    return tuple(view), ax
+
+
+def _leaf_block(view: Tuple[int, ...], ax: int, itemsize: int,
+                budget: int = _BLOCK_BYTES) -> Tuple[int, ...]:
+    """Tile of a view that loads about ``budget`` bytes of fp32: the node
+    axis whole, then whole dims from the minor end while they fit, one dim
+    cut to a multiple of its tile, the dims above it 1.  Dims count at
+    their padded (8·4/itemsize, 128) tile sizes, as in VMEM; a dim that is
+    tiled in the kernel's node-leading output is cut to that output's
+    tile too."""
+    def tiles(ndim):
+        t = [1] * ndim
+        t[-1] = 128
+        if ndim > 1:
+            t[-2] = 8 * 4 // itemsize
+        return t
+
+    nd = len(view)
+    tile = tiles(nd)
+    rest = [k for k in range(nd) if k != ax]
+    for k, t in zip(rest, tiles(nd)[1:]):
+        tile[k] = max(tile[k], t)
+    room = budget // 4 // (-(-view[ax] // tile[ax]) * tile[ax])
+    block = list(view)
+    for k in reversed(rest):
+        padded = -(-view[k] // tile[k]) * tile[k]
+        if padded <= room:
+            room //= padded
+        else:
+            block[k] = min(view[k], max(tile[k], room // tile[k] * tile[k]))
+            room = 1
+    return tuple(block)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("with_g", "with_residual", "wire", "block_d",
-                     "interpret"))
-def _mix_flat(xf: jax.Array, gf: Optional[jax.Array],
-              gamma: Optional[jax.Array], d: jax.Array, M: jax.Array, *,
-              with_g: bool, with_residual: bool, wire: bool,
-              block_d: int, interpret: bool):
-    """Run the fused kernel over an already-flattened (n, D) matrix."""
-    n, D = xf.shape
-    bd = max(1, min(block_d, D))
-    pad = (-D) % bd
-    if pad:  # zero columns: contribute 0 to mix and residual alike
-        xf = jnp.pad(xf, ((0, 0), (0, pad)))
-        if with_g:
-            gf = jnp.pad(gf, ((0, 0), (0, pad)))
-    Dp = D + pad
+    static_argnames=("ax", "block", "with_g", "with_residual", "with_xbar",
+                     "wire", "dot_chunk", "interpret"))
+def _mix_nodes(x: jax.Array, g: Optional[jax.Array],
+               gamma: Optional[jax.Array], d: jax.Array, M: jax.Array, *,
+               ax: int, block: Tuple[int, ...], with_g: bool,
+               with_residual: bool, with_xbar: bool, wire: bool,
+               dot_chunk: int, interpret: bool):
+    """Run the fused kernel over an array whose axis ``ax`` is the node
+    axis, in ``block`` tiles (the node axis whole).  Returns the mixed
+    array node-leading (``x`` with its node axis moved first, ``x``'s
+    dtype); with residual also x̄ (``(1, *rest)`` fp32; None without
+    ``with_xbar``) and the scalar ``Σ_i ‖x_i − x̄‖²``.  With the node
+    axis leading the mixed array aliases ``x``."""
+    n = x.shape[ax]
+    dims = x.shape[:ax] + x.shape[ax + 1:]
+    tile = block[:ax] + block[ax + 1:]
+    grid = tuple(pl.cdiv(s, b) for s, b in zip(dims, tile))
 
-    def tile(i):
-        return (0, i)
+    def at_x(*ids):  # grid index -> x's block index, the node axis whole
+        return ids[:ax] + (0,) + ids[ax:]
 
+    def at_out(*ids):
+        return (0,) + ids
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs, inputs = [], []
     if with_g:
-        in_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
+        in_specs.append(smem)
         inputs.append(jnp.asarray(gamma, jnp.float32).reshape(1, 1))
-    in_specs.append(pl.BlockSpec((n, bd), tile))
-    inputs.append(xf)
+    if dot_chunk:  # the dot reads d and M whole, from VMEM
+        in_specs += [pl.BlockSpec((n, 1), lambda *ids: (0, 0)),
+                     pl.BlockSpec((n, n), lambda *ids: (0, 0))]
+    else:          # the VPU sum reads them as scalars
+        in_specs += [smem, smem]
+    in_specs.append(pl.BlockSpec(block, at_x))
+    inputs += [jnp.asarray(d, jnp.float32).reshape((n, 1) if dot_chunk
+                                                   else (n,)),
+               jnp.asarray(M, jnp.float32), x]
     if with_g:
-        in_specs.append(pl.BlockSpec((n, bd), tile))
-        inputs.append(gf)
-    in_specs.append(pl.BlockSpec((n, 1), lambda i: (0, 0)))
-    inputs.append(d)
-    in_specs.append(pl.BlockSpec((n, n), lambda i: (0, 0)))
-    inputs.append(M)
+        in_specs.append(pl.BlockSpec(block, at_x))
+        inputs.append(g)
 
-    out_shape = [jax.ShapeDtypeStruct((n, Dp), xf.dtype)]
-    out_specs = [pl.BlockSpec((n, bd), tile)]
+    out_shape = [jax.ShapeDtypeStruct((n,) + dims, x.dtype)]
+    out_specs = [pl.BlockSpec((n,) + tile, at_out)]
+    if with_residual and with_xbar:
+        out_shape.append(jax.ShapeDtypeStruct((1,) + dims, jnp.float32))
+        out_specs.append(pl.BlockSpec((1,) + tile, at_out))
     if with_residual:
-        out_shape.append(jax.ShapeDtypeStruct((1, Dp), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, bd), tile))
         # the residual is a scalar accumulated across the grid: Mosaic
         # stores scalars only to SMEM
         out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.float32))
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        out_specs.append(smem)
 
-    kernel = functools.partial(_mix_kernel, with_g=with_g,
-                               with_residual=with_residual, wire=wire)
-    # the packed (n, Dp) matrix is consumed in place: the mixed output
-    # aliases the x input, so jitted callers never allocate a second copy
-    x_idx = 1 if with_g else 0
+    kernel = functools.partial(
+        _mix_kernel, ax=ax, with_g=with_g, with_residual=with_residual,
+        with_xbar=with_xbar, wire=wire, dims=dims, block=tile,
+        dot_chunk=dot_chunk)
+    # a node-leading x is consumed in place: the mixed output aliases it,
+    # so jitted callers never allocate a second copy
+    aliases = {3 if with_g else 2: 0} if ax == 0 else {}
     out = pl.pallas_call(
         kernel,
-        grid=(Dp // bd,),
+        grid=grid,
         in_specs=in_specs,
         out_specs=tuple(out_specs) if with_residual else out_specs[0],
         out_shape=tuple(out_shape) if with_residual else out_shape[0],
-        input_output_aliases={x_idx: 0},
+        input_output_aliases=aliases,
         interpret=interpret,
     )(*inputs)
-
-    if with_residual:
-        mixed, xbar, r = out
-        return mixed[:, :D], xbar[:, :D], r[0, 0]
-    return out[:, :D]
+    if not with_residual:
+        return out
+    if with_xbar:
+        return out[0], out[1], out[2][0, 0]
+    return out[0], None, out[1][0, 0]
 
 
 # ---------------------------------------------------------------------------
 # Public entry points
 # ---------------------------------------------------------------------------
-def _dispatch_groups(leaves, threshold: int):
-    """Leaf indices grouped per kernel dispatch: one group holding every
-    leaf below ``threshold`` per-node elements (concatenated into the
-    staging buffer), plus one single-leaf group per large leaf (dispatched
-    on ``leaf.reshape(n, -1)`` directly — no staging copy)."""
+def _split_leaves(leaves, threshold: int):
+    """``(small, big)`` leaf indices: leaves below ``threshold`` per-node
+    elements share the concatenation staging buffer, each leaf at or
+    above it is mixed in its own layout."""
     sizes = [int(np.prod(lf.shape[1:], dtype=np.int64)) for lf in leaves]
-    small = [i for i, s in enumerate(sizes) if s < threshold]
-    big = [i for i, s in enumerate(sizes) if s >= threshold]
-    groups = [small] if small else []
-    return groups + [[i] for i in big]
+    return ([i for i, s in enumerate(sizes) if s < threshold],
+            [i for i, s in enumerate(sizes) if s >= threshold])
+
+
+def staged_bytes(params: PyTree, leaf_threshold: Optional[int] = None
+                 ) -> int:
+    """Per-node bytes of a stacked fused round that go through the
+    concatenation staging buffer (fp32): the leaves below the threshold."""
+    leaves = jax.tree.leaves(params)
+    thresh = LEAF_DISPATCH_THRESHOLD if leaf_threshold is None \
+        else leaf_threshold
+    small, _ = _split_leaves(leaves, thresh)
+    return 4 * sum(int(np.prod(leaves[i].shape[1:], dtype=np.int64))
+                   for i in small)
+
+
+def _mix_tree(params: PyTree, grads: Optional[PyTree], gamma, dj, Mj, *,
+              wire: bool, block_d: int, interpret: bool, threshold: int,
+              with_residual: bool, with_xbar: bool):
+    """One round over a node-stacked pytree: the small leaves packed into
+    one ``(n, D)`` dispatch, each large leaf in its own view.  The
+    residual sums over dispatches (the consensus sum decomposes over
+    elements)."""
+    with_g = grads is not None
+    leaves, treedef = jax.tree.flatten(params)
+    gleaves = jax.tree.flatten(grads)[0] if with_g else None
+    n = leaves[0].shape[0]
+    mixed_leaves: list = [None] * len(leaves)
+    xbar_leaves: list = [None] * len(leaves)
+    resid = None
+    kw = dict(with_g=with_g, with_residual=with_residual,
+              with_xbar=with_xbar, wire=wire, interpret=interpret)
+
+    def run(x, g, ax, block, dot_chunk):
+        nonlocal resid
+        out = _mix_nodes(x, g, gamma if with_g else None, dj, Mj,
+                         ax=ax, block=block, dot_chunk=dot_chunk, **kw)
+        if not with_residual:
+            return out, None
+        mixed, xbar, r = out
+        resid = r if resid is None else resid + r
+        return mixed, xbar
+
+    small, big = _split_leaves(leaves, threshold)
+    if small:
+        xf = _pack_rows([leaves[i] for i in small], n)
+        gf = _pack_rows([gleaves[i] for i in small], n) if with_g else None
+        # nodes in the tile's sublanes: the MXU dot, on the chip too
+        mixed, xbar = run(xf, gf, 0, (n, max(1, min(block_d, xf.shape[1]))),
+                          block_d)
+        off = 0
+        for i in small:
+            shape = leaves[i].shape
+            size = int(np.prod(shape[1:], dtype=np.int64))
+            mixed_leaves[i] = (mixed[:, off:off + size].reshape(shape)
+                               .astype(leaves[i].dtype))
+            if xbar is not None:
+                xbar_leaves[i] = (xbar[:, off:off + size].reshape(shape[1:])
+                                  .astype(leaves[i].dtype))
+            off += size
+    for i in big:
+        leaf = leaves[i].reshape(n, 1) if leaves[i].ndim == 1 else leaves[i]
+        order = _memory_order(leaf.shape, leaf.dtype)
+        view, ax = _leaf_view(order, leaf.shape)
+        # the kernel returns the leaf node-leading, its other dims in
+        # memory order
+        rest = [p for p in order if p != 0]
+        back = np.argsort([0] + rest)
+
+        def to_view(a):
+            return a.transpose(order).reshape(view)
+
+        def from_out(a):
+            return a.reshape((a.shape[0],) + tuple(leaf.shape[p] for p in rest)
+                             ).transpose(back)
+
+        block = _leaf_block(view, ax, leaf.dtype.itemsize,
+                            _BLOCK_BYTES // 2 if with_g else _BLOCK_BYTES)
+        g = to_view(gleaves[i].reshape(leaf.shape)) if with_g else None
+        mixed, xbar = run(to_view(leaf), g, ax, block,
+                          _dot_chunk(interpret, block_d))
+        mixed_leaves[i] = from_out(mixed).reshape(leaves[i].shape)
+        if xbar is not None:
+            xbar_leaves[i] = (from_out(xbar).reshape(leaves[i].shape[1:])
+                              .astype(leaf.dtype))
+    mixed_tree = jax.tree.unflatten(treedef, mixed_leaves)
+    if not with_residual:
+        return mixed_tree
+    xbar_tree = jax.tree.unflatten(treedef, xbar_leaves) if with_xbar \
+        else None
+    return mixed_tree, xbar_tree, resid
 
 
 def fused_step_mix(params: PyTree, grads: Optional[PyTree] = None,
@@ -344,7 +581,7 @@ def fused_step_mix(params: PyTree, grads: Optional[PyTree] = None,
                    topology: str = "ring", n_nodes: int, step: int = 0,
                    comm_dtype=None, n_pods: int = 1, block_d: int = 2048,
                    interpret: Optional[bool] = None,
-                   with_residual: bool = False,
+                   with_residual: bool = False, with_xbar: bool = True,
                    leaf_threshold: Optional[int] = None):
     """Fused ``W · (params − γ·grads)`` for one communication round.
 
@@ -352,15 +589,15 @@ def fused_step_mix(params: PyTree, grads: Optional[PyTree] = None,
     trainer's optimizer already produced the half-step iterate); with grads
     and γ it is the simulator's whole SGD+gossip step in one HBM pass.
 
-    Leaves at or above ``leaf_threshold`` per-node elements are dispatched
-    as their own kernel call and skip the concatenation staging buffer;
-    the residual/x̄ outputs are combined exactly across dispatches (the
-    consensus sum decomposes over columns).
+    Leaves at or above ``leaf_threshold`` per-node elements are mixed in
+    their own layout, each by its own kernel call; the rest share the
+    concatenation staging buffer, walked ``block_d`` columns a step.
 
     Returns the mixed pytree; with ``with_residual=True`` returns
     ``(mixed, xbar, residual)`` where ``xbar`` is the node average (leaves
-    without the node axis) and ``residual = Σ_i ‖x_i − x̄‖²`` of the mixed
-    iterate (divide by n for the paper's consensus distance).
+    without the node axis; None with ``with_xbar=False``, which writes no
+    x̄) and ``residual = Σ_i ‖x_i − x̄‖²`` of the mixed iterate (divide by
+    n for the paper's consensus distance).
     """
     if phase not in KERNEL_PHASES:
         raise ValueError(f"phase {phase!r} has no fused kernel "
@@ -369,46 +606,15 @@ def fused_step_mix(params: PyTree, grads: Optional[PyTree] = None,
     thresh = LEAF_DISPATCH_THRESHOLD if leaf_threshold is None \
         else leaf_threshold
     d, M = phase_matrices(phase, topology, n_nodes, step=step, n_pods=n_pods)
-    dj, Mj = jnp.asarray(d), jnp.asarray(M)
     # grid mixing ignores comm_dtype in the reference path — mirror that
     wire = (comm_dtype is not None
             and not (phase == "gossip" and topology == "grid"))
-    with_g = grads is not None
-    if with_g and gamma is None:
+    if grads is not None and gamma is None:
         raise ValueError("grads given without gamma")
-
-    leaves, treedef = jax.tree.flatten(params)
-    gleaves = jax.tree.flatten(grads)[0] if with_g else None
-    n = leaves[0].shape[0]
-    mixed_leaves: list = [None] * len(leaves)
-    xbar_leaves: list = [None] * len(leaves)
-    resid = None
-    for group in _dispatch_groups(leaves, thresh):
-        xf = _pack_rows([leaves[i] for i in group], n)
-        gf = _pack_rows([gleaves[i] for i in group], n) if with_g else None
-        out = _mix_flat(xf, gf, gamma if with_g else None, dj, Mj,
-                        with_g=with_g, with_residual=with_residual,
-                        wire=wire, block_d=block_d, interpret=interp)
-        if with_residual:
-            mixed, xbar, r = out
-            resid = r if resid is None else resid + r
-        else:
-            mixed, xbar = out, None
-        off = 0
-        for i in group:
-            shape, size = leaves[i].shape, \
-                int(np.prod(leaves[i].shape[1:], dtype=np.int64))
-            piece = mixed[:, off:off + size]
-            mixed_leaves[i] = piece.reshape(shape).astype(leaves[i].dtype)
-            if with_residual:
-                xbar_leaves[i] = (xbar[:, off:off + size]
-                                  .reshape(shape[1:])
-                                  .astype(leaves[i].dtype))
-            off += size
-    mixed_tree = jax.tree.unflatten(treedef, mixed_leaves)
-    if with_residual:
-        return mixed_tree, jax.tree.unflatten(treedef, xbar_leaves), resid
-    return mixed_tree
+    return _mix_tree(params, grads, gamma, jnp.asarray(d), jnp.asarray(M),
+                     wire=wire, block_d=block_d, interpret=interp,
+                     threshold=thresh, with_residual=with_residual,
+                     with_xbar=with_xbar)
 
 
 def fused_step_mix_dense(params: PyTree, W: jax.Array, *, n_nodes: int,
@@ -424,7 +630,7 @@ def fused_step_mix_dense(params: PyTree, W: jax.Array, *, n_nodes: int,
     column-stochastic W every step (drop renormalization, per-step
     resampling), so here W is an ``(n, n)`` jax array threaded through jit
     as a regular traced operand: one compiled kernel serves every failure
-    pattern, zero recompiles.  ``_mix_flat`` already treats ``d``/``M`` as
+    pattern, zero recompiles.  The kernel already reads ``d``/``M`` as
     runtime data, so this is the same kernel body as
     :func:`fused_step_mix` — only the factor construction moves into the
     traced graph (``d = diag(W)``, ``M = W − diag(W)``).
@@ -444,26 +650,11 @@ def fused_step_mix_dense(params: PyTree, W: jax.Array, *, n_nodes: int,
             f"fused_step_mix_dense wire-casts to bfloat16 only (got "
             f"comm_dtype={jnp.dtype(comm_dtype)}); use backend='reference'")
     Wj = jnp.asarray(W, jnp.float32)
-    dj = jnp.diagonal(Wj).reshape(n_nodes, 1)
-    Mj = Wj - jnp.diag(jnp.diagonal(Wj))
-    wire = comm_dtype is not None
-
-    leaves, treedef = jax.tree.flatten(params)
-    n = leaves[0].shape[0]
-    mixed_leaves: list = [None] * len(leaves)
-    for group in _dispatch_groups(leaves, thresh):
-        xf = _pack_rows([leaves[i] for i in group], n)
-        mixed = _mix_flat(xf, None, None, dj, Mj, with_g=False,
-                          with_residual=False, wire=wire, block_d=block_d,
-                          interpret=interp)
-        off = 0
-        for i in group:
-            shape = leaves[i].shape
-            size = int(np.prod(shape[1:], dtype=np.int64))
-            mixed_leaves[i] = (mixed[:, off:off + size]
-                               .reshape(shape).astype(leaves[i].dtype))
-            off += size
-    return jax.tree.unflatten(treedef, mixed_leaves)
+    dj = jnp.diagonal(Wj)
+    Mj = Wj - jnp.diag(dj)
+    return _mix_tree(params, None, None, dj, Mj, wire=comm_dtype is not None,
+                     block_d=block_d, interpret=interp, threshold=thresh,
+                     with_residual=False, with_xbar=False)
 
 
 def global_average(params: PyTree, n_nodes: int, *, comm_dtype=None,
@@ -494,14 +685,17 @@ def mix_residual(params: PyTree, grads: Optional[PyTree] = None,
                  gamma: Optional[jax.Array] = None, *, phase: str,
                  topology: str = "ring", n_nodes: int, step: int = 0,
                  comm_dtype=None, n_pods: int = 1, block_d: int = 2048,
-                 interpret: Optional[bool] = None,
+                 interpret: Optional[bool] = None, with_xbar: bool = True,
                  leaf_threshold: Optional[int] = None):
-    """``(W·x, x̄, Σ_i ‖x_i − x̄‖²)`` in one pass — eval without re-reading."""
+    """``(W·x, x̄, Σ_i ‖x_i − x̄‖²)`` in one pass — eval without re-reading.
+    A caller that discards x̄ passes ``with_xbar=False`` (x̄ is then None
+    and the kernel writes none)."""
     return fused_step_mix(params, grads, gamma, phase=phase,
                           topology=topology, n_nodes=n_nodes, step=step,
                           comm_dtype=comm_dtype, n_pods=n_pods,
                           block_d=block_d, interpret=interpret,
-                          with_residual=True, leaf_threshold=leaf_threshold)
+                          with_residual=True, with_xbar=with_xbar,
+                          leaf_threshold=leaf_threshold)
 
 
 # ---------------------------------------------------------------------------

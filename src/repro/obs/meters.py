@@ -154,9 +154,13 @@ def comm_round_fields(params: PyTree, *, phase: str, topology: str,
                       backend: str = "reference", sharded: bool = False,
                       comm_dtype=None, compressor=None,
                       global_compressor=None, model_shards: int = 1,
-                      wires=None, role: str = "round") -> Dict[str, Any]:
+                      wires=None, role: str = "round",
+                      staged_bytes: int = 0) -> Dict[str, Any]:
     """Build one ``comm_round`` record's fields: tags + analytic bytes
-    (``round_wire_bytes``) + measured bytes (live tree/wires)."""
+    (``round_wire_bytes``) + measured bytes (live tree/wires) + the
+    per-node bytes a pallas round still packs into its ``(n, D)``
+    staging buffer (``staged_bytes``: 0 where every leaf is mixed in its
+    own layout or the backend is the reference)."""
     import jax
     import numpy as np
     from repro.compress import round_wire_bytes
@@ -186,7 +190,7 @@ def comm_round_fields(params: PyTree, *, phase: str, topology: str,
         "compression": comp_name, "global_compression": gcomp_name,
         "sends": round_sends(phase, topology, n_nodes, step),
         "analytic_bytes": int(analytic), "measured_bytes": int(measured),
-        "traced": traced,
+        "staged_bytes": int(staged_bytes), "traced": traced,
     }
 
 

@@ -302,10 +302,11 @@ def build_train_step(model: Model, tcfg: TrainConfig, n_nodes: int, *,
                     step=shift_step, with_residual=True)
             else:
                 from repro.kernels import mixing_pallas
-                mixed, _xbar, resid = mixing_pallas.mix_residual(
+                mixed, _, resid = mixing_pallas.mix_residual(
                     params_half, phase=phase, topology=dist.topology,
                     n_nodes=n_nodes, step=shift_step,
                     comm_dtype=spec.comm_dtype, n_pods=dist.n_pods,
+                    with_xbar=False,
                     leaf_threshold=dist.pallas_leaf_threshold)
             fused_consensus = resid / n_nodes
         if mixed is None:

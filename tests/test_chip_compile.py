@@ -7,8 +7,12 @@ stacked nodes) for one chip of a described ``v5e:2x2``.  Nothing runs: a
 compile that passes says the chip's compiler accepts the kernel, not that
 its numbers are right (chip_smoke.py checks those on the chip).
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -107,3 +111,89 @@ def test_shard_mix_block_with_residual_compiles_for_v5e(stacked, one_chip):
         x, xs, w, m, with_residual=True, interpret=False)).lower(
         sds(1, d), sds(2, d), sds(1, 1), sds(1, 2)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def gpt2_stacked(one_chip, no_persistent_cache):
+    """The 4-node stacked pga-lm-100m params at GPT-2's vocabulary (50257
+    rows: no multiple of 8), as the benchmark's configuration runs it."""
+    cfg = dataclasses.replace(get_model_config("pga-lm-100m"),
+                              vocab_size=50257)
+    params = jax.eval_shape(lambda k: make_model(cfg).init(k)[0],
+                            jax.random.PRNGKey(0))
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (N,) + a.shape, a.dtype, sharding=one_chip), params)
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\(?[a-z0-9]+\[[0-9,]*\])\S*"
+                    r".*? ([a-z-]+)\((.*?)\)")
+
+
+def _entry(hlo: str):
+    """name -> (first result shape, opcode, operand names) of the entry
+    computation's instructions."""
+    body = hlo[hlo.index("\nENTRY"):]
+    body = body[:body.index("\n}")]
+    out = {}
+    for line in body.splitlines()[1:]:
+        m = _INSTR.match(line)
+        if m:
+            name, shape, op, args = m.groups()
+            out[name] = (shape.lstrip("("), op,
+                         re.findall(r"%([\w.\-]+)", args))
+    return out
+
+
+@pytest.mark.parametrize("phase,step", [("gossip", 0), ("gossip", 1),
+                                        ("global", 0)])
+def test_round_mixes_each_large_leaf_in_place(phase, step, gpt2_stacked,
+                                              topo, monkeypatch):
+    """The train step's round (consensus residual, no x̄) at pga-lm-100m's
+    full leaf tree on a v5e: every leaf at or above the dispatch threshold
+    reaches its kernel as a bitcast of the parameter, with no copy,
+    transpose or loop between them, and each kernel's first result is the
+    mixed leaf, ``f32[4,…]`` (the prefix ``mix_round_roofline`` reads).
+    The one large leaf whose memory holds its nodes inside each tile (the
+    embedding, laid out as ``(50257, 4, 768)``) comes back node-leading and
+    takes one copy into its parameter's layout; every other kernel mixes
+    its leaf in place.  The leaves take the chip's default layouts, so the
+    program asks the described chip for them."""
+    dev = topo.devices[0]
+    monkeypatch.setattr(
+        mp, "_memory_order", lambda shape, dtype: tuple(
+            dev.client.get_default_layout(np.dtype(dtype), shape, dev)
+            ._xla_layout().minor_to_major()[::-1]))
+    compiled = jax.jit(lambda t: mp.mix_residual(
+        t, phase=phase, topology="one_peer_exp", n_nodes=N, step=step,
+        with_xbar=False, interpret=False), donate_argnums=0).lower(
+        gpt2_stacked).compile()
+    hlo = compiled.as_text()
+    instrs = _entry(hlo)
+    large = N * mp.LEAF_DISPATCH_THRESHOLD
+
+    def elems(shape):
+        return int(np.prod([int(d) for d in
+                            re.findall(r"\d+", shape.split("[")[1])]))
+
+    def source(name):
+        # back through bitcasts, tuple elements and XLA's memory-space
+        # prefetches (copy-start/-done: the same layout in another memory)
+        while instrs[name][1] in ("bitcast", "get-tuple-element",
+                                  "copy-start", "copy-done"):
+            name = instrs[name][2][0]
+        return name
+
+    kernels = {k: v for k, v in instrs.items() if v[1] == "custom-call"}
+    n_large = sum(a.size >= large for a in jax.tree.leaves(gpt2_stacked))
+    assert len(kernels) == n_large + 1          # one more: the small leaves
+    fed = 0
+    for shape, _, args in kernels.values():
+        assert shape.startswith(f"f32[{N},"), shape
+        fed += instrs[source(args[-1])][1] == "parameter"
+    assert fed == n_large
+    in_place = hlo.count("output_to_operand_aliasing={{0}:")
+    assert in_place == len(kernels) - 1
+    moved = [(k, op) for k, (shape, op, args) in instrs.items()
+             if op in ("copy", "transpose", "while") and elems(shape) >= large]
+    assert len(moved) == 1 and moved[0][1] == "copy", moved
+    assert source(instrs[moved[0][0]][2][0]) in kernels

@@ -261,6 +261,134 @@ def test_aliasing_does_not_clobber_caller_input(rng_key):
 
 
 # ---------------------------------------------------------------------------
+# Large leaves in their own layout: ragged edges, sub-tile minor dims
+# ---------------------------------------------------------------------------
+# (per-node shape, block budget in bytes): the two small leaves take a budget
+# that cuts them into tiles with a ragged edge; the third sits exactly at the
+# dispatch threshold and takes the default budget
+NATIVE_LEAVES = [((131, 96), 80 << 10), ((2, 24, 3, 64), 80 << 10),
+                 ((512, 512), mp._BLOCK_BYTES)]
+# the memory order XLA:TPU gives these leaves' default layouts on a v5e
+# (node axis second-minor for the first: its 131 rows are no multiple of 8)
+V5E_ORDER = {(131, 96): (1, 0, 2), (2, 24, 3, 64): (0, 1, 3, 2, 4)}
+
+
+def _native_round(tree, n, phase, comm_dtype, **kw):
+    return mp.mix_residual(tree, phase=phase, topology="one_peer_exp",
+                           n_nodes=n, step=1, comm_dtype=comm_dtype,
+                           n_pods=2, **kw)
+
+
+@pytest.mark.parametrize("wire", [False, True])
+@pytest.mark.parametrize("phase", ["gossip", "global", "pod_avg"])
+@pytest.mark.parametrize("n", [4, 8])
+@pytest.mark.parametrize("leaf,budget", NATIVE_LEAVES,
+                         ids=["ragged_rows", "subtile_minor", "at_threshold"])
+def test_native_leaf_round(leaf, budget, n, phase, wire, rng_key,
+                           monkeypatch):
+    """A leaf at or above the threshold, mixed in its own view, against
+    the reference round and (n = 4) the packed staging path; x̄ and the
+    residual exact up to summation order, so the ragged edge's rows
+    count nowhere.  Run twice: as the CPU interprets it (dot of the node
+    mix, leaf as laid out on the host) and as the chip runs it (VPU node
+    sum, the leaf in v5e's memory order)."""
+    monkeypatch.setattr(mp, "_BLOCK_BYTES", budget)
+    cd = jnp.bfloat16 if wire else None
+    x = {"w": jax.random.normal(rng_key, (n,) + leaf)}
+    thresh = min(mp.LEAF_DISPATCH_THRESHOLD, int(np.prod(leaf)))
+    spec = mixing.CommSpec(topology="one_peer_exp", n_nodes=n, n_pods=2,
+                           comm_dtype=cd)
+    want = mixing.communicate(x, spec, phase=phase, step=1)["w"]
+    want_bar = jnp.mean(want, axis=0)
+    want_r = float(jnp.sum((want - want_bar) ** 2))
+    atol = 1e-5 if cd is None else 3e-2
+    packed = _native_round(x, n, phase, cd, leaf_threshold=10**9)
+
+    runs = [_native_round(x, n, phase, cd, leaf_threshold=thresh)]
+    monkeypatch.setattr(mp, "_dot_chunk", lambda interpret, block_d: 0)
+    monkeypatch.setattr(
+        mp, "_memory_order",
+        lambda shape, dtype: V5E_ORDER.get(shape[1:],
+                                           tuple(range(len(shape)))))
+    runs.append(_native_round(x, n, phase, cd, leaf_threshold=thresh))
+    for mixed, xbar, resid in runs:
+        assert mixed["w"].shape == x["w"].shape
+        np.testing.assert_allclose(mixed["w"], want, atol=atol)
+        np.testing.assert_allclose(xbar["w"], jnp.mean(mixed["w"], 0),
+                                   atol=1e-6)
+        got_r = float(jnp.sum((mixed["w"] - xbar["w"]) ** 2))
+        np.testing.assert_allclose(float(resid), got_r, rtol=1e-5)
+        if cd is None:
+            np.testing.assert_allclose(xbar["w"], want_bar, atol=1e-5)
+            np.testing.assert_allclose(float(resid), want_r, rtol=1e-4,
+                                       atol=1e-5)
+        if n == 4:
+            np.testing.assert_allclose(mixed["w"], packed[0]["w"],
+                                       atol=1e-6)
+            np.testing.assert_allclose(xbar["w"], packed[1]["w"], atol=1e-6)
+            np.testing.assert_allclose(float(resid), float(packed[2]),
+                                       rtol=1e-5)
+
+
+def test_round_without_xbar_writes_none(rng_key):
+    """``with_xbar=False`` (the train step's round) returns no x̄ and the
+    same mixed tree and residual."""
+    tree = {"big": jax.random.normal(rng_key, (4, 131, 96)),
+            "small": jax.random.normal(rng_key, (4, 7))}
+    kw = dict(phase="gossip", topology="ring", n_nodes=4, leaf_threshold=64)
+    m0, x0, r0 = mp.mix_residual(tree, **kw)
+    m1, x1, r1 = mp.mix_residual(tree, with_xbar=False, **kw)
+    assert x0 is not None and x1 is None
+    _assert_tree_close(m1, m0, atol=0)
+    assert float(r1) == float(r0)
+
+
+def test_leaf_block_follows_the_shape():
+    """Blocks come from the view and the budget: whole minor dims, the
+    node axis whole, about the budget's bytes a step."""
+    budget = mp._BLOCK_BYTES
+    # pga-lm-100m's leaves at n = 4, in v5e's memory order
+    assert mp._leaf_block((4, 12, 768, 3072), 0, 4) == (4, 1, 40, 3072)
+    assert mp._leaf_block((4, 144, 64, 768), 0, 4) == (4, 2, 64, 768)
+    # the node axis tiled in memory: the rows are tiled in the kernel's
+    # node-leading output, so they are cut to a multiple of 8
+    assert mp._leaf_block((50257, 4, 768), 1, 4) == (80, 4, 768)
+    for view, ax in (((4, 12, 768, 3072), 0), ((4, 144, 64, 768), 0),
+                     ((50257, 4, 768), 1)):
+        block = mp._leaf_block(view, ax, 4)
+        # a node axis in the sublane position pads to 8 rows in VMEM
+        pad = 8 // block[ax] if ax == len(view) - 2 else 1
+        nbytes = 4 * int(np.prod(block)) * pad
+        assert budget // 2 < nbytes <= budget
+    assert mp._leaf_view((1, 0, 2), (4, 50257, 768)) == ((50257, 4, 768), 1)
+    assert mp._leaf_view((0, 1, 3, 4, 2), (4, 12, 768, 12, 64)) == \
+        ((4, 144, 64, 768), 0)
+    assert mp._leaf_view((0, 1, 2, 3), (4, 12, 768, 3072)) == \
+        ((4, 12, 768, 3072), 0)
+
+
+@pytest.mark.parametrize("n", [4, 8, 32])
+def test_chip_round_mixes_packed_nodes_on_the_mxu(n, monkeypatch):
+    """Compiled for the chip, the packed group (nodes in a tile's
+    sublanes) mixes with the ``(n, n) @ (n, block_d)`` dot and a large
+    leaf's view (node axis untiled) with the VPU sum, at every n."""
+    seen = []
+    real = mp._mix_nodes
+
+    def spy(x, *args, **kw):
+        seen.append((x.ndim, kw["dot_chunk"]))
+        return real(x, *args, **kw)
+
+    monkeypatch.setattr(mp, "_mix_nodes", spy)
+    tree = {"big": jax.ShapeDtypeStruct((n, 4, 64, 128), jnp.float32),
+            "small": jax.ShapeDtypeStruct((n, 96), jnp.float32)}
+    jax.eval_shape(lambda t: mp.fused_step_mix(
+        t, phase="global", n_nodes=n, block_d=512, interpret=False,
+        leaf_threshold=4 * 64 * 128), tree)
+    assert sorted(seen) == [(2, 512), (4, 0)]
+
+
+# ---------------------------------------------------------------------------
 # shard_map-aware sharded path (8 forced host devices, subprocess)
 # ---------------------------------------------------------------------------
 _SHARDED_PARITY_SCRIPT = textwrap.dedent("""

@@ -168,6 +168,34 @@ def test_comm_round_measured_matches_analytic(compression, phase):
     assert r["measured_bytes"] == want     # packed-buffer bytes agree
 
 
+@pytest.mark.parametrize("round_,backend,want", [
+    ("phase", "pallas", 7 * 4), ("phase", "reference", 0),
+    ("push_sum", "pallas", 7 * 4 + 4), ("push_sum", "reference", 0)])
+def test_comm_round_staged_bytes(round_, backend, want):
+    """A pallas round reports the per-node bytes that still ride its
+    packed staging buffer: the leaves below the dispatch threshold (the
+    (n, 32) leaf is mixed in its own layout), and in a push-sum round the
+    weight column beside them; the reference round none."""
+    n = 4
+    params = [jnp.ones((n, 32), jnp.float32), jnp.ones((n, 7), jnp.float32)]
+    spec = DistConfig(algorithm="gossip_pga", topology="ring",
+                      comm_backend=backend,
+                      pallas_leaf_threshold=16).comm_spec(n)
+    tel = obs.Telemetry(sinks=[obs.RingSink()])
+    with obs.telemetry_scope(tel):
+        if round_ == "phase":
+            for phase in ("gossip", "global"):
+                mixing.communicate(params, spec, phase=phase, step=0)
+        else:
+            for _ in range(2):
+                mixing.communicate_push_sum(
+                    params, jnp.ones((n, 1), jnp.float32),
+                    W=jnp.full((n, n), 1.0 / n), n_nodes=n, backend=backend,
+                    leaf_threshold=16)
+    recs = tel.ring().records("comm_round")
+    assert [r["staged_bytes"] for r in recs] == [want, want]
+
+
 def test_comm_round_meter_noop_without_hub():
     n = 4
     params = [jnp.ones((n, 8), jnp.float32)]
